@@ -197,27 +197,39 @@ class DualCoreRunner:
         self.jit_groups = jit_groups
         if donate is None:           # donation is a no-op on CPU backends
             donate = jax.default_backend() in ("tpu", "gpu")
+        self._donate = donate
+        self._fns = self._compile_all()
+
+    def _compile_all(self) -> list:
         # group 0 must not donate: its env holds the caller's image array,
         # which re-runs (timed reps, warm-up + measure) reuse
-        self._fns = [self._compile(i, donate and i > 0)
-                     for i in range(len(self.groups))]
+        return [self._compile(i, self._donate and i > 0)
+                for i in range(len(self.groups))]
 
     def _compile(self, gi: int, donate: bool):
-        steps = self.groups[gi].steps
+        group = self.groups[gi]
         live = self.plan.live_after[gi]
 
         def group_fn(params: Params, env: Env) -> Env:
             env = dict(env)
-            for s in steps:
+            for s in group.steps:
                 s.fn(params, env, None)
             return {k: v for k, v in env.items() if k in live}
 
         if not self.jit_groups:
             return group_fn
+        fn = group_fn
+        mesh = self._shard[group.core].mesh
+        if mesh.size > 1:
+            # the TPU compiler cannot partition a Pallas kernel: on a
+            # multi-chip submesh every chip runs the whole group on its
+            # replica of params and env (P(): replicated, as placed)
+            fn = jax.shard_map(group_fn, mesh=mesh, in_specs=(P(), P()),
+                               out_specs=P(), check_vma=False)
         if donate:                   # inter-group buffer donation: the env
             #                          flows linearly through the chain
-            return jax.jit(group_fn, donate_argnums=(1,))
-        return jax.jit(group_fn)
+            return jax.jit(fn, donate_argnums=(1,))
+        return jax.jit(fn)
 
     def _place(self, env: Env, core: str) -> Env:
         if not self._distinct:
@@ -240,11 +252,10 @@ class DualCoreRunner:
 
     def relocate(self, dual: DualMesh) -> None:
         """Move this runner onto a re-split pool (the runner-side half of
-        a REBALANCE): rebuild the shardings for the new c/p submeshes and
-        re-place the resident params.  The jitted group fns are kept —
-        XLA retraces a call whose argument shardings changed, so
-        correctness is preserved and recompilation happens lazily, only
-        for groups that actually run again."""
+        a REBALANCE): rebuild the shardings for the new c/p submeshes,
+        re-place the resident params and re-wrap the group fns for the
+        new submeshes (jit is lazy: recompilation happens only for groups
+        that actually run again)."""
         self.dual = dual
         self._distinct = dual.c_mesh is not dual.p_mesh
         self._shard = {"c": NamedSharding(dual.c_mesh, P()),
@@ -252,6 +263,7 @@ class DualCoreRunner:
         self._params = {core: jax.device_put(self._params[core],
                                              self._shard[core])
                         for core in ("c", "p")}
+        self._fns = self._compile_all()
 
     # ------------------------------------------------------------------
     def run_pipelined(self, images, record: list | None = None):
@@ -289,6 +301,23 @@ class DualCoreRunner:
             jax.block_until_ready(env["out"])
             outs.append(env["out"])
         return outs
+
+    def trace_groups(self, x) -> list[tuple]:
+        """Run one input through the chain as :meth:`run_sequential`
+        does, returning each exec group's compiled program (what a RUN
+        dispatches; ``.as_text()`` is the HLO the device runs) with the
+        env it produced — how a caller checks which kernels a group runs
+        and which devices hold its output."""
+        out = []
+        env = self.place_input(x)
+        for g, (fn, h) in enumerate(zip(self._fns, self.handles)):
+            if g > 0 and h.core != self.groups[g - 1].core:
+                env = self._place(env, h.core)
+            params = self._params[h.core]
+            compiled = fn.lower(params, env).compile()
+            env = fn(params, env)
+            out.append((compiled, env))
+        return out
 
     # ------------------------------------------------------------------
     def timed(self, images, mode: str = "pipelined",

@@ -804,10 +804,9 @@ class MultiPoolRouter(EngineBase):
         target = min(cands, key=self._outstanding)
         ex = self.executors[target]
         mix = normalize_mix({m.name: m.weight for m in ex.fleet.members})
-        try:
-            self.rebalance(target, mix=mix, theta=ex.fleet.pool.theta)
-        except Exception:   # degraded-but-alive beats a re-lease error
-            pass            # escalating a crash we already survived
+        # a failed re-lease raises: swallowing it would let the run exit 0
+        # on a survivor whose split is in an unknown state
+        self.rebalance(target, mix=mix, theta=ex.fleet.pool.theta)
 
     def _check_degradation(self) -> None:
         """Degrade pools whose RUN timeouts crossed ``timeout_strikes``:

@@ -410,7 +410,9 @@ def main(argv=None) -> int:
                    help="modeled compute: microseconds each sim member "
                         "sleeps per occupied slot (sim fleets only)")
     args = p.parse_args(argv)
+    from repro.launch.compile_cache import enable_compile_cache
 
+    enable_compile_cache()
     if args.sim:
         try:
             fleet = build_sim_fleet(args.sim, policy=args.policy,

@@ -141,46 +141,48 @@ def get_config(sig: LayerSig, path: str | None = None) -> dict | None:
 
 
 def heuristic_config(sig: LayerSig) -> dict:
-    """Default block shapes used on a cache miss — the pre-tuner behaviour."""
+    """Default block shapes used on a cache miss.  Channel blocks are
+    lane-aligned (``lane_tile``: the full dim or a multiple of 128); the
+    kernels size their output-row tiles to the VMEM budget themselves, so
+    the full channel count is the default wherever channels are blocked."""
+    from repro.kernels.util import lane_tile
     if sig.kind == "conv":
         wo = max(1, (sig.W + 2 * sig.pad - sig.K_w) // sig.stride + 1)
         ho = max(1, (sig.H + 2 * sig.pad - sig.K_h) // sig.stride + 1)
         return {"block_h": max(1, min(ho, -(-256 // wo))),
-                "block_n": min(128, max(sig.C_o, 8))}
+                "block_n": lane_tile(128, sig.C_o)}
     if sig.kind == "pointwise":
         return {"block": (128, 128, 128)}
     if sig.kind == "depthwise":
-        # largest channel block whose halo tile fits half a core's VMEM
-        tile = (sig.H + sig.K_h - 1) * (sig.W + sig.K_w - 1) * 4
-        bc = max(8, (8 * 1024 * 1024) // max(tile, 1))
-        bc = min(bc, sig.C_i)
-        return {"block_c": max(8, bc - bc % 8) if bc >= 8 else max(1, bc)}
+        return {"block_c": sig.C_i}
     if sig.kind in ("fused_dw_pw", "fused_pw_dw_pw"):
-        return {"block_c": min(128, max(sig.C_i, 8)),
-                "block_n": min(128, max(sig.C_o, 8))}
+        return {"block_c": sig.C_i, "block_n": lane_tile(128, sig.C_o)}
     raise ValueError(f"unknown kernel kind {sig.kind!r}")
 
 
 def candidates(sig: LayerSig) -> list[dict]:
-    """Small per-kind candidate sets (kept tiny: interpret mode is slow)."""
+    """Small per-kind candidate sets (kept tiny: interpret mode is slow).
+    Every channel block is lane-aligned, so each candidate is one the TPU
+    compiler accepts: a failing candidate is an error, not a skip."""
+    from repro.kernels.util import lane_tile
     out: list[dict] = [heuristic_config(sig)]
     if sig.kind == "conv":
         ho = max(1, (sig.H + 2 * sig.pad - sig.K_h) // sig.stride + 1)
         for bh in (1, 4, 8, 16):
-            for bn in (64, 128):
+            for bn in (128, 256):
                 out.append({"block_h": min(bh, ho),
-                            "block_n": min(bn, max(sig.C_o, 8))})
+                            "block_n": lane_tile(bn, sig.C_o)})
     elif sig.kind == "pointwise":
-        for b in ((64, 64, 64), (128, 128, 128), (256, 128, 128)):
+        for b in ((128, 128, 128), (256, 128, 128), (256, 256, 256)):
             out.append({"block": b})
     elif sig.kind == "depthwise":
-        for bc in (32, 64, 128):
-            out.append({"block_c": min(bc, max(sig.C_i, 1))})
+        for bc in (128, 256):
+            out.append({"block_c": lane_tile(bc, sig.C_i)})
     else:
-        for bc in (64, 128):
-            for bn in (64, 128):
-                out.append({"block_c": min(bc, max(sig.C_i, 8)),
-                            "block_n": min(bn, max(sig.C_o, 8))})
+        for bc in (128, sig.C_i):
+            for bn in (128, 256):
+                out.append({"block_c": lane_tile(bc, sig.C_i),
+                            "block_n": lane_tile(bn, sig.C_o)})
     # dedupe, preserving order
     seen: set[str] = set()
     uniq = []
@@ -216,20 +218,12 @@ def tune(sig: LayerSig, run: Callable[[dict], Callable[[], Any]], *,
     import jax
     best_cfg, best_us = None, float("inf")
     for cfg in candidates(sig):
-        try:
-            us = _time_us(run(cfg), reps=reps)
-        except Exception:            # a candidate may be invalid for a shape
-            continue
+        us = _time_us(run(cfg), reps=reps)
         if us < best_us:
             best_cfg, best_us = cfg, us
-    if best_cfg is None:
-        # every candidate failed: cache the heuristic with no timing (null
-        # keeps the JSON strict — NaN is not valid JSON)
-        best_cfg, best_us = heuristic_config(sig), None
     data = load_cache(path)
     data["entries"][sig.key()] = {"config": best_cfg,
-                                  "us": None if best_us is None
-                                  else round(best_us, 1),
+                                  "us": round(best_us, 1),
                                   "backend": jax.default_backend()}
     save_cache(data, path)
     return dict(best_cfg)
@@ -368,10 +362,9 @@ def sweep_zoo(image_size: int = 224, *, reps: int = 3, limit: int = 0,
     todo = missing if limit <= 0 else missing[:limit]
     for i, sig in enumerate(todo):
         cfg = tune_layer(sig, path=path, reps=reps, force=force)
-        entry = load_cache(path)["entries"][sig.key()]
-        us = entry.get("us")
+        us = load_cache(path)["entries"][sig.key()]["us"]
         print(f"[{i + 1:>3}/{len(todo)}] {sig.key():<48} -> {cfg} "
-              f"({'n/a' if us is None else f'{us:.0f} us'})")
+              f"({us:.0f} us)")
     summary = {"image_size": image_size, "total": len(sigs),
                "cached": len(cached), "tuned": len(todo),
                "skipped": len(missing) - len(todo),

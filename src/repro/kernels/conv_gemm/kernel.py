@@ -9,12 +9,16 @@ tile *inside the kernel* from a halo tile resident in VMEM, so HBM traffic is
 ~1x the ifm (DESIGN.md §1).  ``im2col`` survives only in ref.py as the test
 oracle.
 
-Grid: (N, C_o tiles, H_out tiles), with the H_out tiles innermost so the
-image block (index map independent of the inner dims) stays VMEM-resident
-across a whole output-channel pass.  Each step runs K_h*K_w MXU dots of
-(block_h*W_out, C_i) @ (C_i, block_n) accumulated in a float32 VMEM scratch
-(the overlay's output-buffer partial sums, §III-A), then a fused
-bias + ReLU/ReLU6 epilogue (the overlay's post-processing unit).
+Grid: (N, H_out tiles, C_o tiles).  Each step DMAs only the halo rows its
+output-row block reads (an element-indexed block, so VMEM use is bounded
+whatever the image size) and keeps them resident across the inner C_o
+tiles.  Each step runs K_h*K_w MXU dots of (block_h*W_out, C_i) @
+(C_i, block_n), each tap read from the halo ref (``util.window_tap``:
+contiguous reads only — the TPU compiler refuses strided slices of loaded
+values and strided ref reads wider than one lane tile), accumulated in a
+float32 VMEM scratch (the overlay's output-buffer partial sums, §III-A),
+then a fused bias + ReLU/ReLU6 epilogue (the overlay's post-processing
+unit).
 
 ``matmul_bias_act`` is the plain tiled GEMM used by the 1x1 (pointwise / fc)
 fast path, where im2col is the identity.  Block shapes default to
@@ -29,8 +33,10 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.util import (apply_act, cdiv, pad_axis, pad_to,
-                                resolve_interpret)
+from repro.kernels.util import (apply_act, cdiv, halo_block, lane_tile,
+                                mxu_dot, pad_axis, pad_to,
+                                resolve_interpret, row_tiling,
+                                split_w_phases, vmem_row_bytes, window_tap)
 
 
 DEFAULT_BLOCK = (128, 128, 128)  # (block_m, block_n, block_k)
@@ -61,8 +67,7 @@ def _matmul_kernel(x_ref, w_ref, *rest, nk: int, fuse_bias: bool,
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    acc_ref[...] += jnp.dot(x_ref[...], w_ref[...],
-                            preferred_element_type=jnp.float32)
+    acc_ref[...] += mxu_dot(x_ref[...], w_ref[...])
 
     @pl.when(pl.program_id(2) == nk - 1)
     def _epilogue():
@@ -85,8 +90,8 @@ def matmul_bias_act(x: jax.Array, w: jax.Array, bias: jax.Array | None = None,
     K2, N = w.shape
     assert K == K2, (x.shape, w.shape)
     bm = min(block[0], max(M, 8))
-    bn = min(block[1], max(N, 8))
-    bk = min(block[2], max(K, 8))
+    bn = lane_tile(block[1], N)
+    bk = lane_tile(block[2], K)
     xp = pad_to(x, (bm, bk))
     wp = pad_to(w, (bk, bn))
     fuse_bias = bias is not None
@@ -119,44 +124,35 @@ def matmul_bias_act(x: jax.Array, w: jax.Array, bias: jax.Array | None = None,
 # implicit-GEMM conv (K > 1): no HBM patch matrix, ever
 # --------------------------------------------------------------------------
 def _implicit_gemm_kernel(x_ref, w_ref, *rest, kh: int, kw: int, stride: int,
-                          bh: int, wo: int, fuse_bias: bool, act: str | None):
-    """One (n, co, ht) grid step of the implicit GEMM.
+                          wh: int, fuse_bias: bool, act: str | None):
+    """One (n, ht, co) grid step of the implicit GEMM.
 
-    x_ref:   (1, Hp, Wp, C)  — the whole padded image, VMEM-resident (its
-             index map ignores co/ht, so Pallas keeps it loaded across the
-             inner grid dims: HBM traffic ~1x the ifm).
+    x_ref:   (1, span_h, Wp, C) — the halo rows of the padded image that
+             this output-row block reads (an element-indexed block: tiles
+             overlap by kh - 1 rows, HBM traffic ~1x the ifm), columns
+             stride-phase split (phase width ``wh``).
     w_ref:   (kh, kw, C, bn)
     b_ref:   (1, bn) — only present when ``fuse_bias``
     o_ref:   (1, bh, wo, bn)
     acc_ref: (bh*wo, bn) float32 VMEM scratch accumulator.
 
-    The (bh*wo, C) patch tile for each window tap is gathered from the halo
-    tile with strided VMEM slices — the in-kernel im2col — and fed straight
-    to the MXU.
+    The (bh*wo, C) patch tile of each window tap is read from the halo
+    ref (``window_tap``) — the in-kernel im2col — and fed straight to the
+    MXU.
     """
     if fuse_bias:
         b_ref, o_ref, acc_ref = rest
     else:
         (o_ref, acc_ref), b_ref = rest, None
-    ht = pl.program_id(2)
-    x = x_ref[0]                       # (Hp, Wp, C)
-    _, wp_, c = x.shape
-    span_h = (bh - 1) * stride + kh
-    # halo rows for this output-row block (dynamic start, static size)
-    xs = jax.lax.dynamic_slice(x, (ht * bh * stride, 0, 0),
-                               (span_h, wp_, c))
+    _, bh, wo, bn = o_ref.shape
+    c = x_ref.shape[3]
     acc_ref[...] = jnp.zeros_like(acc_ref)
     for i in range(kh):                # unrolled window taps: each gathers a
         for j in range(kw):            # patch tile from the same VMEM halo
-            tap = jax.lax.slice(
-                xs, (i, j, 0),
-                (i + (bh - 1) * stride + 1, j + (wo - 1) * stride + 1, c),
-                (stride, stride, 1))   # (bh, wo, c)
-            acc_ref[...] += jnp.dot(tap.reshape(bh * wo, c),
-                                    w_ref[i, j],
-                                    preferred_element_type=jnp.float32)
+            tap = window_tap(x_ref, (0,), i, j, bh, wo, stride, wh)
+            acc_ref[...] += mxu_dot(tap.reshape(bh * wo, c), w_ref[i, j])
     out = _apply_epilogue(acc_ref[...], b_ref, act)
-    o_ref[0] = out.reshape(bh, wo, -1).astype(o_ref.dtype)
+    o_ref[0] = out.reshape(bh, wo, bn).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("stride", "pad", "act",
@@ -172,7 +168,8 @@ def conv2d_implicit_gemm(x: jax.Array, w: jax.Array,
 
     x: (N, H, W, C_i); w: (K_h, K_w, C_i, C_o); bias: (C_o,) or None.
     ``block_h`` output rows per grid step (0 = auto: aim for a ~256-row
-    GEMM M-tile); ``block_n`` output-channel tile.
+    GEMM M-tile), capped so the halo tile fits the VMEM budget;
+    ``block_n`` output-channel tile (lane-aligned by ``lane_tile``).
     """
     interpret = resolve_interpret(interpret)
     n, h, wd, ci = x.shape
@@ -180,35 +177,37 @@ def conv2d_implicit_gemm(x: jax.Array, w: jax.Array,
     assert ci == ci2, (x.shape, w.shape)
     ho = (h + 2 * pad - kh) // stride + 1
     wo = (wd + 2 * pad - kw) // stride + 1
-    bh = block_h if block_h > 0 else max(1, min(ho, cdiv(256, wo)))
-    bh = min(bh, ho)
-    bn = min(block_n, max(co, 8))
-    n_ht = cdiv(ho, bh)
-    # spatial padding: conv pad plus extra bottom rows so the last h-tile's
-    # halo slice stays in bounds ((n_ht*bh - 1)*stride + kh rows needed)
-    need_h = (n_ht * bh - 1) * stride + kh
-    extra_h = max(0, need_h - (h + 2 * pad))
-    xp = jnp.pad(x, ((0, 0), (pad, pad + extra_h), (pad, pad), (0, 0)))
+    wp_ = wd + 2 * pad
+    bn = lane_tile(block_n, co)
+    row = (2 * stride * vmem_row_bytes(wp_, ci)       # halo, double-buffered
+           + 3 * vmem_row_bytes(wo, bn))              # acc + output x2
+    bh, n_ht, span_h, extra_h = row_tiling(
+        ho, stride, kh, h + 2 * pad, row,
+        limit=block_h if block_h > 0 else cdiv(256, wo))
+    xp, wh = split_w_phases(
+        jnp.pad(x, ((0, 0), (pad, pad + extra_h), (pad, pad), (0, 0))),
+        stride)
+    wx = xp.shape[2]
     wp = pad_axis(w, 3, bn)
     cop = wp.shape[3]
     fuse_bias = bias is not None
-    hp, wp_ = xp.shape[1], xp.shape[2]
-    grid = (n, cop // bn, n_ht)
+    grid = (n, n_ht, cop // bn)
     in_specs = [
-        pl.BlockSpec((1, hp, wp_, ci), lambda i, j, t: (i, 0, 0, 0)),
-        pl.BlockSpec((kh, kw, ci, bn), lambda i, j, t: (0, 0, 0, j)),
+        pl.BlockSpec(halo_block(span_h, wx, ci),
+                     lambda i, t, j: (i, t * bh * stride, 0, 0)),
+        pl.BlockSpec((kh, kw, ci, bn), lambda i, t, j: (0, 0, 0, j)),
     ]
     operands = [xp, wp]
     if fuse_bias:
-        in_specs.append(pl.BlockSpec((1, bn), lambda i, j, t: (0, j)))
+        in_specs.append(pl.BlockSpec((1, bn), lambda i, t, j: (0, j)))
         operands.append(pad_to(bias.reshape(1, co), (1, bn)))
     out = pl.pallas_call(
         functools.partial(_implicit_gemm_kernel, kh=kh, kw=kw, stride=stride,
-                          bh=bh, wo=wo, fuse_bias=fuse_bias, act=act),
+                          wh=wh, fuse_bias=fuse_bias, act=act),
         grid=grid,
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, bh, wo, bn),
-                               lambda i, j, t: (i, t, 0, j)),
+                               lambda i, t, j: (i, t, 0, j)),
         out_shape=jax.ShapeDtypeStruct((n, n_ht * bh, wo, cop), x.dtype),
         scratch_shapes=[pltpu.VMEM((bh * wo, bn), jnp.float32)],
         interpret=interpret,

@@ -1,24 +1,11 @@
-"""Dispatch wrapper for the depthwise kernel with VMEM-aware channel
-blocking (autotuned per layer signature when a cache entry exists)."""
+"""Dispatch wrapper for the depthwise kernel (channel block autotuned per
+layer signature when a cache entry exists, else the heuristic)."""
 from __future__ import annotations
 
 import jax
 
 from repro.kernels import autotune
 from repro.kernels.depthwise.kernel import depthwise_conv2d
-
-VMEM_BUDGET_BYTES = 8 * 1024 * 1024   # half of a v5e core's VMEM for x-tile
-
-
-def pick_block_c(h: int, w: int, c: int, kh: int, kw: int,
-                 bytes_per_elem: int = 4) -> int:
-    """Largest channel block whose halo tile fits the VMEM budget — the
-    Eq.2-style knob of the p-core port: T_c here plays the role of (n,v)."""
-    tile = (h + kh - 1) * (w + kw - 1) * bytes_per_elem
-    bc = max(8, VMEM_BUDGET_BYTES // max(tile, 1)) if tile else c
-    bc = min(bc, c)
-    # round down to a multiple of 8 (VPU sublane)
-    return max(8, bc - bc % 8) if bc >= 8 else max(1, bc)
 
 
 def depthwise(x: jax.Array, w: jax.Array, bias: jax.Array | None = None,
@@ -31,7 +18,7 @@ def depthwise(x: jax.Array, w: jax.Array, bias: jax.Array | None = None,
         sig = autotune.LayerSig(kind="depthwise", H=h, W=wd, C_i=c, C_o=c,
                                 K_h=kh, K_w=kw, stride=stride, pad=pad,
                                 dtype=str(x.dtype))
-        cfg = autotune.get_config(sig)
-        block_c = cfg["block_c"] if cfg else pick_block_c(h, wd, c, kh, kw)
+        cfg = autotune.get_config(sig) or autotune.heuristic_config(sig)
+        block_c = cfg["block_c"]
     return depthwise_conv2d(x, w, bias, stride=stride, pad=pad, act=act,
                             block_c=min(block_c, c), interpret=interpret)

@@ -1,15 +1,19 @@
 """Shared helpers for the Pallas kernel packages.
 
-Every kernel wrapper needs the same three things: ceil-division for grids,
-zero-padding up to block multiples (so BlockSpec grids divide evenly), and a
-backend-aware default for Pallas ``interpret`` mode — interpret on CPU (this
-container / CI), compiled on a real TPU.  They live here so conv_gemm /
-depthwise / fused_block / attention / rmsnorm stay in sync (DESIGN.md §5).
+Every kernel wrapper needs the same things: ceil-division for grids,
+zero-padding up to block multiples (so BlockSpec grids divide evenly), a
+backend-aware default for Pallas ``interpret`` mode — interpret on CPU
+(tests), compiled on a real TPU — and, for the conv family, the tiling the
+TPU compiler accepts: lane-aligned channel blocks, output-row tiles whose
+halo fits the VMEM budget, and window taps that never slice a loaded value
+with a stride.  They live here so conv_gemm / depthwise / fused_block /
+attention / rmsnorm stay in sync (DESIGN.md §5).
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
 
 
 def cdiv(a: int, b: int) -> int:
@@ -37,6 +41,102 @@ def pad_axis(x: jax.Array, axis: int, mult: int) -> jax.Array:
     pads = [(0, 0)] * x.ndim
     pads[axis] = (0, extra)
     return jnp.pad(x, pads)
+
+
+LANES = 128                          # last-axis tile of a TPU vreg
+SUBLANES = 8                         # second-to-last axis tile (32-bit)
+# VMEM one grid step of a conv-family kernel may plan for (inputs double-
+# buffered, scratch, outputs).  The scoped VMEM default of a v5e core is
+# 16 MiB, so tiles sized to this fit with room for Mosaic's own temporaries.
+VMEM_TILE_BUDGET = 6 * 1024 * 1024
+
+
+def lane_tile(block: int, dim: int) -> int:
+    """A block size the TPU compiler accepts on a lane (last) axis: the
+    full ``dim`` when ``block`` covers it (or ``dim`` fits one lane tile),
+    else ``block`` rounded down to a multiple of 128."""
+    if block >= dim or dim <= LANES:
+        return dim
+    return max(LANES, block - block % LANES)
+
+
+def vmem_row_bytes(w: int, c: int, itemsize: int = 4) -> int:
+    """VMEM footprint of one (w, c) row of an NHWC tile, with the
+    sublane/lane padding the TPU layout adds."""
+    return (cdiv(w, SUBLANES) * SUBLANES * cdiv(c, LANES) * LANES
+            * itemsize)
+
+
+def row_tiling(out_rows: int, stride: int, kh: int, in_rows: int,
+               row_bytes: int, *, limit: int = 0,
+               budget: int = VMEM_TILE_BUDGET) -> tuple[int, int, int, int]:
+    """Tile the output rows of a KxK conv so one grid step's tiles stay
+    within ``budget`` (``row_bytes`` = VMEM per output row, halo
+    included; ``limit`` caps the rows when given).  Returns ``(bh, n_ht,
+    span, extra)``: rows per tile (evened out so the last tile is not
+    mostly padding), the tile count, the input rows one tile reads (tap
+    ``i`` reads ``stride*bh`` contiguous rows from row ``i``, see
+    :func:`window_tap`), and the bottom rows to pad the ``in_rows``-row
+    (already conv-padded) input by so the last tile's halo stays in
+    bounds."""
+    bh = max(1, min(out_rows, budget // max(row_bytes, 1)))
+    if limit > 0:
+        bh = min(bh, limit)
+    n_ht = cdiv(out_rows, bh)
+    bh = cdiv(out_rows, n_ht)
+    span = stride * bh + kh - 1
+    return bh, n_ht, span, max(0, (n_ht - 1) * bh * stride + span - in_rows)
+
+
+def halo_block(span: int, w: int, c: int):
+    """Element-indexed block of ``span`` rows x the full padded width: the
+    index map returns the first row of each output-row tile's halo, so
+    consecutive tiles overlap by the window's extra rows and one grid step
+    holds only its own rows in VMEM."""
+    return (pl.Element(1), pl.Element(span), pl.Element(w), pl.Element(c))
+
+
+def split_w_phases(xp: jax.Array, stride: int) -> tuple[jax.Array, int]:
+    """Stride-phase split of the W axis of a padded NHWC map: column
+    ``k*stride + p`` moves to ``p*wh + k``.  Every window tap then reads
+    ``wo`` *contiguous* columns.  The TPU compiler refuses a strided slice
+    of a loaded value, and a strided ref read wider than one lane tile, so
+    stride-2 taps are never strided along W in-kernel.  Returns the
+    rearranged map and the phase width ``wh``."""
+    if stride == 1:
+        return xp, xp.shape[2]
+    n, h, w, c = xp.shape
+    wh = cdiv(w, stride)
+    xp = pad_axis(xp, 2, stride)
+    xp = xp.reshape(n, h, wh, stride, c).transpose(0, 1, 3, 2, 4)
+    return xp.reshape(n, h, stride * wh, c), wh
+
+
+def window_tap(ref, lead: tuple, i: int, j: int, bh: int, wo: int,
+               stride: int, wh: int, chan=slice(None)) -> jax.Array:
+    """Tap (i, j) of a KxK window over a halo tile ``ref`` indexed
+    ``ref[*lead, rows, cols, chan]`` whose columns are phase-split by
+    :func:`split_w_phases` (phase width ``wh``) -> (bh, wo, C).  Rows are
+    one contiguous read of ``stride*bh`` rows whose every ``stride``-th is
+    kept by a reshape of the (untiled, so free) leading axis."""
+    col = (j % stride) * wh + j // stride
+    v = ref[(*lead, pl.ds(i, stride * bh), pl.ds(col, wo), chan)]
+    if stride > 1:
+        v = v.reshape(bh, stride, wo, v.shape[-1])[:, 0]
+    return v
+
+
+def mxu_dot(a: jax.Array, b: jax.Array) -> jax.Array:
+    """The kernels' one MXU contraction, accumulated in f32.  f32 operands
+    are contracted at full f32 precision: Mosaic's default runs an f32 dot
+    as a single bf16 pass (2.4e-3 relative error per 256x512x256 matmul on
+    a v5e, against 1.8e-7 at HIGHEST), which compounds to ~2.5e-2 over
+    mobilenet_v2 — a model served "in f32" must compute in f32.  Lower
+    precision is a dtype choice (bf16 operands), not a silent default."""
+    precision = (jax.lax.Precision.HIGHEST if a.dtype == jnp.float32
+                 else None)
+    return jnp.dot(a, b, preferred_element_type=jnp.float32,
+                   precision=precision)
 
 
 def apply_act(x: jax.Array, act: str | None) -> jax.Array:

@@ -39,7 +39,6 @@ from repro.launch.sharding import (batch_spec, cache_specs, param_specs,
 from repro.lm.config import ArchConfig
 from repro.lm.model import decode_step, init_cache, init_params
 from repro.lm.steps import TrainState, make_train_step
-from repro.meshcompat import use_mesh
 from repro.train.optimizer import AdamW
 
 HBM_PER_CHIP = 16 * 1024 ** 3          # v5e
@@ -221,7 +220,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
                         else None))
         batch_tree = inputs
         jitted = jax.jit(step, donate_argnums=(0,))
-        with use_mesh(mesh):            # ambient mesh for pshard hints
+        with jax.set_mesh(mesh):        # ambient mesh for pshard hints
             lowered = jitted.lower(state_sds, batch_tree)
     else:
         max_len = seq if kind != "prefill" else seq
@@ -243,7 +242,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
                 return decode_step(params, cfg, token, cache)
             args = (params_sds, inputs["tokens"], cache_sds)
         jitted = jax.jit(step, donate_argnums=(2,))
-        with use_mesh(mesh):            # ambient mesh for pshard hints
+        with jax.set_mesh(mesh):        # ambient mesh for pshard hints
             lowered = jitted.lower(*args)
 
     t_lower = time.time() - t0

@@ -82,22 +82,61 @@ Fleet (several CNNs multiplexed over one device pool, DESIGN.md §10):
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import sys
+from typing import Any
 
 import jax
+import numpy as np
 
 from repro.configs.registry import ARCH_IDS, get_arch, get_smoke
 from repro.dualmesh import (DualMeshRunner, TpuModel, plan_admission,
                             request_stages, search, split_mesh)
+from repro.launch.compile_cache import enable_compile_cache
 from repro.lm.model import init_params
 from repro.serving import (DualCoreEngine, DualMeshEngine, Request,
-                           poisson_arrivals, replay)
+                           ServeResult, poisson_arrivals, replay)
 
 CNN_MODELS = ("mobilenet_v1", "mobilenet_v2", "squeezenet")
 CNN_SCHEMES = ("layer_type", "greedy", "round_robin", "balanced", "best")
 MODEL_ALIASES = {"mbv1": "mobilenet_v1", "mbv2": "mobilenet_v2",
                  "sqz": "squeezenet",
                  **{m: m for m in CNN_MODELS}}
+
+
+@dataclasses.dataclass
+class Served:
+    """What one ``serve`` run handed back: the requests it submitted, the
+    engine's result (completions and outputs in submission order), the
+    dual-core runner of each CNN member, and the CLI exit code."""
+
+    requests: list[Request]
+    result: ServeResult | None
+    runners: dict[str, Any] = dataclasses.field(default_factory=dict)
+    rc: int = 0
+
+
+def _print_devices(use_pallas: bool = True) -> None:
+    """Header of every in-process run: platform, device kind and count as
+    JAX reports them, and whether the Pallas kernels compile or run in
+    interpret mode — so a CPU run can never read as a chip run."""
+    from repro.kernels.util import default_interpret
+
+    d = jax.devices()
+    kernels = ("xla (--no-pallas)" if not use_pallas else
+               "interpret" if default_interpret() else "compiled")
+    print(f"[serve] devices: platform={d[0].platform} "
+          f"kind={d[0].device_kind} count={len(d)} kernels={kernels}")
+
+
+def images(n: int, batch: int, size: int,
+           seed: int = 0) -> list[np.ndarray]:
+    """``n`` seeded (batch, size, size, 3) f32 request payloads, made with
+    numpy: building them never initialises a JAX backend, so a parent
+    that spawns worker processes leaves every device to them."""
+    rng = np.random.default_rng(seed)
+    return [rng.standard_normal((batch, size, size, 3), dtype=np.float32)
+            for _ in range(n)]
 
 
 def _fail(msg: str) -> None:
@@ -121,7 +160,7 @@ def _print_latency(metrics) -> None:
           f"{metrics.completed} requests")
 
 
-def serve_cnn(args) -> int:
+def serve_cnn(args) -> Served:
     """``cnn`` subcommand: streaming CNN serving on the c/p submeshes."""
     from repro.core.arch import BoardModel, DUAL_BASELINE
     from repro.core.scheduler import best_schedule, build_schedule
@@ -136,19 +175,18 @@ def serve_cnn(args) -> int:
     else:
         sched = build_schedule(graph, DUAL_BASELINE, board, args.scheme)
 
+    _print_devices(not args.no_pallas)
     runner = DualCoreRunner(args.model, params, sched,
                             use_pallas=not args.no_pallas)
     es = runner.plan.exec_schedule
     n = args.requests
-    keys = jax.random.split(jax.random.PRNGKey(0), n)
-    images = [jax.random.normal(k, (args.batch, args.image_size,
-                                    args.image_size, 3)) for k in keys]
-    runner.run_sequential(images[:1])           # warm the per-group jits
+    payloads = images(n, args.batch, args.image_size)
+    runner.run_sequential(payloads[:1])         # warm the per-group jits
 
     engine = DualCoreEngine(runner, max_queue=args.max_queue)
-    res = replay(engine, [Request(x) for x in images],
-                 _arrivals(n, args.arrival_rate))
-    _, t_seq = runner.timed(images, "sequential", reps=2)
+    requests = [Request(x) for x in payloads]
+    res = replay(engine, requests, _arrivals(n, args.arrival_rate))
+    _, t_seq = runner.timed(payloads, "sequential", reps=2)
 
     degenerate = runner.dual.c_mesh is runner.dual.p_mesh
     sim = simulate_dual_core(es)
@@ -170,7 +208,7 @@ def serve_cnn(args) -> int:
           f"sequential {t_seq*1e3:.0f} ms "
           f"({t_seq/s['wall_s']:.2f}x)")
     _print_latency(res.metrics)
-    return 0
+    return Served(requests, res, {args.model: runner})
 
 
 class _MetricsSink:
@@ -254,7 +292,7 @@ def _parse_fleet_mix(args) -> dict[str, float]:
         _fail(str(e))
 
 
-def _serve_fleet_workers(args, mix, build, requests, arrivals) -> int:
+def _serve_fleet_workers(args, mix, build, requests, arrivals) -> Served:
     """``fleet --workers N --transport socket``: each pool is a real
     worker process (``python -m repro.fleet.worker``) hosting the same
     CNN fleet; the coordinator drives them over ``SocketTransport``
@@ -365,7 +403,7 @@ def _serve_fleet_workers(args, mix, build, requests, arrivals) -> int:
     if done != n or st["duplicates_dropped"] or st["failed"]:
         print("repro.launch.serve: error: exactly-once retirement "
               "violated", file=sys.stderr)
-        return 1
+        return Served(requests, res, rc=1)
     if args.verify_replay:
         from repro.fleet.compiler import stream_signature
 
@@ -376,7 +414,7 @@ def _serve_fleet_workers(args, mix, build, requests, arrivals) -> int:
                     fresh.executors[p].records):
                 print(f"repro.launch.serve: error: replay diverged on "
                       f"{p}", file=sys.stderr)
-                return 1
+                return Served(requests, res, rc=1)
         print(f"[serve] replay verified: "
               f"{sum(len(r) for r in streams.values())} records across "
               f"{len(streams)} pool(s) replay bitwise on fresh "
@@ -391,10 +429,10 @@ def _serve_fleet_workers(args, mix, build, requests, arrivals) -> int:
             json.dump(doc, f)
         print(f"[serve] wrote {len(doc['traceEvents'])} trace events to "
               f"{args.trace} (open in chrome://tracing)")
-    return 0
+    return Served(requests, res)
 
 
-def serve_fleet(args) -> int:
+def serve_fleet(args) -> Served:
     """``fleet`` subcommand: multi-network serving over one device pool —
     or over ``--pools N`` process-local pools (hosts stand-in) behind a
     ``MultiPoolRouter``, each pool replaying its own compiled instruction
@@ -482,14 +520,13 @@ def serve_fleet(args) -> int:
 
     n = args.requests
     tags = mix_schedule(mix, n)
-    keys = jax.random.split(jax.random.PRNGKey(0), n)
-    images = [jax.random.normal(k, (args.batch, args.image_size,
-                                    args.image_size, 3)) for k in keys]
-    requests = [Request(x, model=t) for x, t in zip(images, tags)]
+    payloads = images(n, args.batch, args.image_size)
+    requests = [Request(x, model=t) for x, t in zip(payloads, tags)]
     arrivals = _arrivals(n, args.arrival_rate)
 
     if args.workers:
         return _serve_fleet_workers(args, mix, build, requests, arrivals)
+    _print_devices(not args.no_pallas)
 
     sink = _MetricsSink(args)
 
@@ -509,7 +546,7 @@ def serve_fleet(args) -> int:
         for m in engine.members:         # warm each member's per-group jits
             # any image warms a member — a skewed mix or --requests <
             # number of models can leave a member with no tagged request
-            m.engine.runner.run_sequential(images[:1])
+            m.engine.runner.run_sequential(payloads[:1])
         s = pool.stats()
         print(f"[serve] fleet {'+'.join(mix)} policy={args.policy} "
               f"({s['c_chips']}c+{s['p_chips']}p devices"
@@ -551,6 +588,7 @@ def serve_fleet(args) -> int:
                   f"final weights {weights}")
         streams = {"pool0": engine.stream}
         roof_src, steps_done = engine, st["slots"]
+        runners = {m.name: m.engine.runner for m in engine.members}
     else:
         fleets = {f"pool{p}": build()[0] for p in range(args.pools)}
         controllers = {name: attach_controller(fl)
@@ -571,7 +609,7 @@ def serve_fleet(args) -> int:
             transport=transport)
         for fleet_engine in fleets.values():
             for m in fleet_engine.members:
-                m.engine.runner.run_sequential(images[:1])
+                m.engine.runner.run_sequential(payloads[:1])
         print(f"[serve] fleet {'+'.join(mix)} x {args.pools} pools "
               f"policy={args.policy} (requests placed on the least "
               f"outstanding pool)")
@@ -604,6 +642,7 @@ def serve_fleet(args) -> int:
         streams = {name: ex.records
                    for name, ex in router.executors.items()}
         roof_src, steps_done = router, st["steps"]
+        runners = {}
     sink.finish(steps_done)
     if args.trace:
         import json
@@ -616,10 +655,10 @@ def serve_fleet(args) -> int:
         print(f"[serve] wrote {len(doc['traceEvents'])} trace events to "
               f"{args.trace} (roofline-annotated; open in "
               f"chrome://tracing)")
-    return 0
+    return Served(requests, res, runners)
 
 
-def serve_lm(args) -> int:
+def serve_lm(args) -> Served:
     """``lm`` subcommand: dual-mesh continuous batching."""
     cfg = get_smoke(args.arch) if args.smoke else get_arch(args.arch)
     n_streams = args.streams or max(1, args.requests)
@@ -635,6 +674,7 @@ def serve_lm(args) -> int:
               f"planned makespan={res.makespan*1e3:.1f} ms "
               f"tokens/s={res.tokens_per_s:.0f} on {args.plan_chips} chips")
 
+    _print_devices(use_pallas=False)
     params = init_params(cfg, jax.random.PRNGKey(0))
     dual = split_mesh(jax.devices(), theta)
     plan = plan_admission(cfg, dual, TpuModel(), args.batch,
@@ -653,9 +693,8 @@ def serve_lm(args) -> int:
     engine = DualMeshEngine(runner, group_size=group_size,
                             prefill_chunk=args.prefill_chunk,
                             max_queue=args.max_queue)
-    res = replay(engine,
-                 [Request(p, gen_steps=args.gen) for p in prompts],
-                 _arrivals(n, args.arrival_rate))
+    requests = [Request(p, gen_steps=args.gen) for p in prompts]
+    res = replay(engine, requests, _arrivals(n, args.arrival_rate))
     s = res.stats
     print(f"[serve] {n} requests x {args.batch} batch: "
           f"{s['wall_s']*1e3:.0f} ms ({s['tokens_per_s']:.0f} tok/s, "
@@ -664,7 +703,7 @@ def serve_lm(args) -> int:
     _print_latency(res.metrics)
     for kind, mesh_name, t in res.trace:
         print(f"  {kind:<8} on {mesh_name}-mesh  {t*1e3:7.1f} ms")
-    return 0
+    return Served(requests, res)
 
 
 def _add_common(ap: argparse.ArgumentParser) -> None:
@@ -678,7 +717,8 @@ def _add_common(ap: argparse.ArgumentParser) -> None:
                     help="bounded request queue (backpressure beyond it)")
 
 
-def main(argv=None):
+def parse_args(argv=None) -> argparse.Namespace:
+    """The ``serve`` command line, validated (usage errors exit 2)."""
     ap = argparse.ArgumentParser(
         prog="repro.launch.serve",
         description="Serve the LM or the CNN through the shared "
@@ -833,7 +873,19 @@ def main(argv=None):
         ap.error(f"--max-queue must be >= 1, got {args.max_queue}")
     if not args.arrival_rate > 0:
         ap.error(f"--arrival-rate must be > 0, got {args.arrival_rate}")
+    return args
+
+
+def run(argv=None) -> Served:
+    """Serve one command line and hand back what was served — the entry
+    point the CLI and ``chip_smoke.py`` share."""
+    args = parse_args(argv)
+    enable_compile_cache()
     return args.func(args)
+
+
+def main(argv=None) -> int:
+    return run(argv).rc
 
 
 if __name__ == "__main__":

@@ -1,9 +1,8 @@
 """Activation sharding hints (MaxText-style logical constraints).
 
 ``hint(x, 'batch', None, 'model')`` applies a with_sharding_constraint
-resolved against the ambient mesh (repro.meshcompat.use_mesh /
-current_mesh, portable across the jax.set_mesh API move).  Outside any
-mesh (CPU
+resolved against the ambient mesh (entered with ``with jax.set_mesh(m):``,
+read with ``jax.sharding.get_abstract_mesh``).  Outside any mesh (CPU
 smoke tests) it is a no-op; axes that are missing from the mesh or do not
 divide the dimension are dropped (same fallback policy as
 repro.launch.sharding).
@@ -20,8 +19,6 @@ import math
 
 import jax
 from jax.sharding import PartitionSpec as P
-
-from repro.meshcompat import current_mesh
 
 BATCH = "batch"
 MODEL = "model"
@@ -41,7 +38,9 @@ def dp_only() -> bool:
 
 
 def _mesh():
-    return current_mesh()
+    """The ambient mesh, or None outside any ``jax.set_mesh`` context."""
+    mesh = jax.sharding.get_abstract_mesh()
+    return None if mesh.empty else mesh
 
 
 def hint(x, *logical):

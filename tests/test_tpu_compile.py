@@ -1,0 +1,88 @@
+"""The CNN kernels compile for a TPU v5e at their 224-px zoo shapes.
+
+Interpret mode (every other kernel test) cannot see what the TPU compiler
+refuses: dynamic slices of loaded values, strided slices, strided reads
+wider than one lane tile, blocks that are not lane-aligned, tiles that
+overflow VMEM.  Each test here compiles one kernel for a *described* v5e
+chip — no chip attached — and checks the program holds the Pallas kernel
+(``tpu_custom_call``).  Nothing runs, so nothing here is a time.
+
+The topology is described inside a module-scoped fixture, never at import
+time: only one process may load the TPU library, and under pytest-xdist
+every worker imports this file.  Keep these tests in this one file.
+"""
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels.conv_gemm.ops import conv2d_gemm
+from repro.kernels.depthwise.ops import depthwise
+from repro.kernels.fused_block.ops import fused_dw_pw, fused_inverted_residual
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    import os
+
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a program compiled for a described chip is written to the persistent
+    # cache but cannot be read back without one: keep the cache off here
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+
+
+def _compile(fn, sharding, *shapes):
+    args = [jax.ShapeDtypeStruct(s, jnp.float32, sharding=sharding)
+            for s in shapes]
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    return compiled
+
+
+# (x shape, w shape, stride): the 3x3 s2 stem of all three zoo models
+def test_stem_conv_compiles(one_chip):
+    _compile(lambda x, w, b: conv2d_gemm(x, w, b, stride=2, pad=1,
+                                         act="relu6", interpret=False),
+             one_chip, (1, 224, 224, 3), (3, 3, 3, 32), (32,))
+
+
+@pytest.mark.parametrize("shape,stride", [
+    ((1, 112, 112, 64), 2),        # mobilenet_v1 dw2: stride 2
+    ((1, 28, 28, 256), 2),         # mobilenet_v1 dw6: C > 128, stride 2
+    ((1, 56, 56, 144), 1),         # mobilenet_v2 b3_dw: C > 128, C % 128
+])
+def test_depthwise_compiles(one_chip, shape, stride):
+    c = shape[-1]
+    _compile(lambda x, w, b: depthwise(x, w, b, stride=stride, pad=1,
+                                       act="relu6", interpret=False),
+             one_chip, shape, (3, 3, c), (c,))
+
+
+def test_fused_inverted_residual_stride2_compiles(one_chip):
+    """mobilenet_v2 b2: pw-expand 16->96, dw s2, pw-project 96->24."""
+    _compile(lambda x, ew, eb, dw, db, pw, pb: fused_inverted_residual(
+                 x, ew, eb, dw, db, pw, pb, stride=2, pad=1,
+                 interpret=False),
+             one_chip, (1, 112, 112, 16), (16, 96), (96,), (3, 3, 96),
+             (96,), (96, 24), (24,))
+
+
+def test_fused_dw_pw_compiles(one_chip):
+    """mobilenet_v1 dw1+pw1: dw 3x3 s1 on 32 channels, pw 32->64."""
+    _compile(lambda x, dw, db, pw, pb: fused_dw_pw(
+                 x, dw, db, pw, pb, stride=1, pad=1, pw_act="relu6",
+                 interpret=False),
+             one_chip, (1, 112, 112, 32), (3, 3, 32), (32,), (32, 64),
+             (64,))

@@ -216,6 +216,10 @@ class DualCoreRunner:
                 s.fn(params, env, None)
             return {k: v for k, v in env.items() if k in live}
 
+        # a stable name per exec group: the device trace shows the
+        # program as ``jit_dualcore_g07_p``
+        group_fn.__name__ = group_fn.__qualname__ = \
+            f"dualcore_g{gi:02d}_{group.core}"
         if not self.jit_groups:
             return group_fn
         fn = group_fn

@@ -10,14 +10,6 @@ every submesh idle gap of >= 1 slot inside the pool's active window —
 labeled with what the idle submesh could have run next, so a pipeline
 bubble is a named event, not something to squint for.
 
-With a ``roofline`` model (``{pool: {member: roofline_fps}}``, see
-:func:`roofline_model`) every RUN slice additionally carries
-``achieved_fps`` (advances over the slice's wall window),
-``roofline_fps`` (the member's latency-model advance-rate ceiling), and
-``roofline_util`` — their ratio clamped to 1.05, since wall clocks on a
-host are not the board clock the model prices; the raw ratio is always
-recoverable from the other two args.
-
 Only executed records carry wall-clock stamps; compiled-only records
 (``t0 is None``) are skipped and *counted* — the skip count comes back
 from :func:`write_chrome_trace` so callers can report rather than
@@ -34,10 +26,6 @@ from repro.fleet.instructions import (ExecRecord, Free, Rebalance, Recv,
 
 # track (tid) layout within each pool's process row; lower sorts first
 _TRACKS = ("c-submesh", "p-submesh", "retire", "control", "bubbles")
-
-#: clamp for the RUN-slice roofline utilization arg (host wall clocks
-#: are not the board clock; see module docstring)
-_UTIL_CLAMP = 1.05
 
 
 def _track(instr) -> str:
@@ -66,44 +54,6 @@ def _label(instr, advances: int) -> str:
     if isinstance(instr, SetParam):
         return f"SET {instr.member}.{instr.param}={instr.value}"
     return type(instr).__name__
-
-
-def roofline_model(obj) -> dict[str, dict[str, float]]:
-    """``{pool: {member: roofline_fps}}`` from live engines.
-
-    Accepts a ``MultiPoolRouter`` (walks ``.executors``, taking each
-    pool executor's local fleet), one ``FleetEngine`` (one pool), or an
-    already-shaped mapping (passed through).  A member's ceiling is the
-    latency model's advance rate: one slot advances a stream one exec
-    group, and a group costs at least ``min(group_latencies)`` cycles,
-    so ``roofline_fps = freq_mhz * 1e6 / min(group_latencies)``.
-    Members without a pipeline latency model (service stubs, opaque
-    engines, remote executors whose members live in another process)
-    are skipped — their RUN slices carry no roofline args.
-    """
-    executors = getattr(obj, "executors", None)
-    if executors is not None:                       # MultiPoolRouter
-        fleets = {name: ex.fleet for name, ex in executors.items()
-                  if getattr(ex, "fleet", None) is not None}
-    elif isinstance(obj, Mapping):
-        return dict(obj)
-    else:                                           # one FleetEngine
-        fleets = {getattr(obj.executor, "name", "pool0"): obj}
-    out: dict[str, dict[str, float]] = {}
-    for pool, fleet in fleets.items():
-        per: dict[str, float] = {}
-        for m in getattr(fleet, "members", ()):
-            runner = getattr(m.engine, "runner", None)
-            if runner is None or not hasattr(runner, "plan"):
-                continue
-            sched = runner.plan.exec_schedule
-            lats = list(sched.group_latencies)
-            if not lats or min(lats) <= 0:
-                continue
-            per[m.name] = sched.board.freq_mhz * 1e6 / min(lats)
-        if per:
-            out[pool] = per
-    return out
 
 
 def _bubbles(records: Sequence[ExecRecord]) -> list[dict]:
@@ -155,16 +105,13 @@ def _bubbles(records: Sequence[ExecRecord]) -> list[dict]:
     return out
 
 
-def chrome_trace(streams: Mapping[str, Sequence[ExecRecord]], *,
-                 roofline: Mapping[str, Mapping[str, float]] | None = None
-                 ) -> dict:
+def chrome_trace(streams: Mapping[str, Sequence[ExecRecord]]) -> dict:
     """``{pool name: records}`` -> a Chrome trace-event document.
 
     Every executed record becomes one complete ('X') event: ``ts``/``dur``
     in microseconds from the records' wall-clock window, filed under its
     pool's process and its submesh's thread, with slot / seq / advances
-    in ``args`` for the details pane.  ``roofline`` adds per-RUN
-    utilization args and is keyed like :func:`roofline_model`'s result.
+    in ``args`` for the details pane.
     """
     stamped = [r for recs in streams.values() for r in recs
                if r.t0 is not None and r.t1 is not None]
@@ -181,21 +128,11 @@ def chrome_trace(streams: Mapping[str, Sequence[ExecRecord]], *,
             events.append({"ph": "M", "pid": pid, "tid": tid,
                            "name": "thread_sort_index",
                            "args": {"sort_index": tid}})
-        pool_roof = (roofline or {}).get(pool, {})
         for r in records:
             if r.t0 is None or r.t1 is None:
                 continue
             args = {"slot": r.slot, "seq": r.seq,
                     "advances": r.advances}
-            if isinstance(r.instr, Run) and r.advances > 0 \
-                    and r.t1 > r.t0:
-                roof = pool_roof.get(r.instr.member)
-                if roof:
-                    achieved = r.advances / (r.t1 - r.t0)
-                    args["achieved_fps"] = round(achieved, 3)
-                    args["roofline_fps"] = round(roof, 3)
-                    args["roofline_util"] = round(
-                        min(achieved / roof, _UTIL_CLAMP), 6)
             events.append({
                 "ph": "X",
                 "pid": pid,
@@ -224,13 +161,11 @@ def chrome_trace(streams: Mapping[str, Sequence[ExecRecord]], *,
 
 
 def write_chrome_trace(streams: Mapping[str, Sequence[ExecRecord]],
-                       path: str, *,
-                       roofline: Mapping[str, Mapping[str, float]] |
-                       None = None) -> tuple[int, int]:
+                       path: str) -> tuple[int, int]:
     """Write :func:`chrome_trace` to ``path``; returns ``(events,
     skipped)`` — the event count and how many compiled-only (unstamped)
     records the export had to leave out."""
-    doc = chrome_trace(streams, roofline=roofline)
+    doc = chrome_trace(streams)
     skipped = sum(1 for recs in streams.values() for r in recs
                   if r.t0 is None or r.t1 is None)
     with open(path, "w") as f:

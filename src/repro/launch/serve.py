@@ -217,13 +217,24 @@ class _MetricsSink:
     snapshot is written once (``-`` = Prometheus text on stdout, ``.json``
     = JSON, else Prometheus text).  With it, one compact
     ``{"step": s, "snapshot": ...}`` JSON line is appended every K steps
-    plus a final line — a replayable time series."""
+    plus a final line — a replayable time series.  While a registry
+    that is written out and enabled is attached, every garbage
+    collection is recorded in it (``gc_pause_seconds``)."""
 
     def __init__(self, args):
         self.path = getattr(args, "metrics", None)
         self.every = getattr(args, "metrics_every", None)
         self.registry = None      # set once the engine/router exists
         self._started = False
+        self._untrack_gc = None
+
+    def attach(self, registry) -> None:
+        """Write ``registry``; track collections into it when enabled."""
+        from repro.obs import track_gc
+
+        self.registry = registry
+        if self.path is not None and registry.enabled:
+            self._untrack_gc = track_gc(registry)
 
     def on_step(self, step: int) -> None:
         if self.registry is None or not self.every:
@@ -245,6 +256,9 @@ class _MetricsSink:
         self._started = True
 
     def finish(self, steps: int) -> None:
+        if self._untrack_gc is not None:
+            self._untrack_gc()
+            self._untrack_gc = None
         if self.registry is None or self.path is None:
             return
         if self.every:
@@ -335,7 +349,7 @@ def _serve_fleet_workers(args, mix, build, requests, arrivals) -> Served:
         fleets = connect(procs, heartbeat_s=recovery.heartbeat_s)
         router = MultiPoolRouter(fleets, recovery=recovery)
         sink = _MetricsSink(args)
-        sink.registry = router.obs
+        sink.attach(router.obs)
 
         def collect_telemetry():
             for ex in router.executors.values():
@@ -552,7 +566,7 @@ def serve_fleet(args) -> Served:
               f"({s['c_chips']}c+{s['p_chips']}p devices"
               + (", degenerate: both submeshes alias one device"
                  if s["degenerate"] else "") + ")")
-        sink.registry = engine.executor.obs
+        sink.attach(engine.executor.obs)
         res = replay(engine, requests, arrivals, on_step=sink.on_step)
         st = res.stats
         print(f"[serve] streamed {n} request(s) in {st['slots']} fleet "
@@ -587,7 +601,7 @@ def serve_fleet(args) -> Served:
                   f"{cs['decisions']} decisions {cs['by_kind'] or '{}'}; "
                   f"final weights {weights}")
         streams = {"pool0": engine.stream}
-        roof_src, steps_done = engine, st["slots"]
+        steps_done = st["slots"]
         runners = {m.name: m.engine.runner for m in engine.members}
     else:
         fleets = {f"pool{p}": build()[0] for p in range(args.pools)}
@@ -613,7 +627,7 @@ def serve_fleet(args) -> Served:
         print(f"[serve] fleet {'+'.join(mix)} x {args.pools} pools "
               f"policy={args.policy} (requests placed on the least "
               f"outstanding pool)")
-        sink.registry = router.obs
+        sink.attach(router.obs)
         res = replay(router, requests, arrivals, on_step=sink.on_step)
         st = res.stats
         print(f"[serve] streamed {n} request(s) over {args.pools} pools "
@@ -641,20 +655,19 @@ def serve_fleet(args) -> Served:
                       f"{cs['by_kind'] or '{}'}")
         streams = {name: ex.records
                    for name, ex in router.executors.items()}
-        roof_src, steps_done = router, st["steps"]
+        steps_done = st["steps"]
         runners = {}
     sink.finish(steps_done)
     if args.trace:
         import json
 
-        from repro.fleet.trace import chrome_trace, roofline_model
+        from repro.fleet.trace import chrome_trace
 
-        doc = chrome_trace(streams, roofline=roofline_model(roof_src))
+        doc = chrome_trace(streams)
         with open(args.trace, "w") as f:
             json.dump(doc, f)
         print(f"[serve] wrote {len(doc['traceEvents'])} trace events to "
-              f"{args.trace} (roofline-annotated; open in "
-              f"chrome://tracing)")
+              f"{args.trace} (open in chrome://tracing)")
     return Served(requests, res, runners)
 
 
@@ -827,9 +840,8 @@ def parse_args(argv=None) -> argparse.Namespace:
     fleet.add_argument("--trace", default=None, metavar="PATH",
                        help="write the executed instruction stream as "
                             "Chrome-tracing JSON to PATH (one track per "
-                            "submesh per pool, roofline args on RUN "
-                            "slices, labeled bubble events; open in "
-                            "chrome://tracing)")
+                            "submesh per pool, labeled bubble events; "
+                            "open in chrome://tracing)")
     fleet.add_argument("--metrics", default=None, metavar="PATH",
                        help="write the telemetry registry at the end of "
                             "the run: '-' = Prometheus text on stdout, "

@@ -9,8 +9,10 @@ and the slot/wall domain contract.
 from repro.obs.export import to_json, to_prometheus, write_metrics
 from repro.obs.registry import (DEFAULT_COUNT_BOUNDS,
                                 DEFAULT_SECONDS_BOUNDS, Counter, Gauge,
-                                Histogram, Registry, parse_label_key)
+                                Histogram, Registry, parse_label_key,
+                                track_gc)
 
 __all__ = ["Counter", "Gauge", "Histogram", "Registry",
            "DEFAULT_COUNT_BOUNDS", "DEFAULT_SECONDS_BOUNDS",
-           "parse_label_key", "to_json", "to_prometheus", "write_metrics"]
+           "parse_label_key", "to_json", "to_prometheus", "track_gc",
+           "write_metrics"]

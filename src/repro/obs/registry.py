@@ -26,9 +26,17 @@ not contain ``','`` or ``'='``.  Snapshots are plain JSON-able dicts —
 what ships over the wire (§14 ``telemetry_snap`` envelopes), merges
 across processes (:meth:`Registry.absorb`), and exports
 (:mod:`repro.obs.export`).
+
+Spans (:meth:`Registry.span`) are ``wall`` data kept apart from the
+metrics: an in-memory list of ``(t0_ns, t1_ns, name, parent, rid,
+group)`` on ``time.perf_counter_ns``, read by :meth:`Registry.spans` and
+never part of a snapshot.  :func:`track_gc` adds every garbage
+collection to them as a ``gc`` span.
 """
 from __future__ import annotations
 
+import gc
+import time
 from typing import Mapping
 
 # seconds-scaled bounds: instruction execution on this stack spans
@@ -39,6 +47,10 @@ DEFAULT_SECONDS_BOUNDS = (1e-4, 3e-4, 1e-3, 3e-3, 1e-2, 3e-2,
 DEFAULT_COUNT_BOUNDS = (0.0, 1.0, 2.0, 4.0, 8.0, 16.0, 32.0)
 
 DOMAINS = ("slot", "wall")
+
+#: spans a registry keeps; later ones are counted in
+#: ``obs_spans_dropped_total`` and not kept
+MAX_SPANS = 1_000_000
 
 
 def _label_key(labels: Mapping[str, str] | None) -> str:
@@ -143,18 +155,115 @@ class Histogram:
         s["n"] += 1
 
 
+class _Span:
+    """One open span of :meth:`Registry.span`; ``rid`` and ``group`` may
+    be set inside the ``with`` block, once they are known."""
+
+    __slots__ = ("registry", "name", "rid", "group", "parent", "index",
+                 "t0")
+
+    def __init__(self, registry: "Registry", name: str, rid, group):
+        self.registry, self.name = registry, name
+        self.rid, self.group = rid, group
+
+    def __enter__(self) -> "_Span":
+        self.registry._open_span(self)
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.registry._close_span(self)
+
+
+class _NoSpan:
+    """What :meth:`Registry.span` hands out when it records nothing."""
+
+    __slots__ = ()
+    rid = group = None
+
+    def __enter__(self) -> "_NoSpan":
+        return self
+
+    def __exit__(self, *exc) -> None:
+        return None
+
+    def __setattr__(self, name, value) -> None:
+        return None
+
+
+_NO_SPAN = _NoSpan()
+
+
 class Registry:
     """A process-local metric namespace (module docstring).
 
-    ``enabled=False`` turns every ``inc``/``set``/``observe`` into a
-    no-op — the bare leg of ``benchmarks/obs_bench.py`` measures the
-    instrumentation overhead against exactly this switch.
+    ``enabled=False`` turns every ``inc``/``set``/``observe`` and every
+    :meth:`span` into a no-op — the bare leg of
+    ``benchmarks/obs_bench.py`` measures the instrumentation overhead
+    against exactly this switch.
     """
 
     def __init__(self, enabled: bool = True):
         self.enabled = enabled
         self._metrics: dict[str, Counter | Gauge | Histogram] = {}
         self._absorbed: dict[str, dict] = {}     # source -> last snapshot
+        self._spans: list[tuple] = []
+        self._open: list[int] = []               # indices of open spans
+
+    # ------------------------------------------------------------------
+    def span(self, name: str, *, rid: int | None = None,
+             group: int | None = None):
+        """A context manager that records one span ``(t0_ns, t1_ns, name,
+        parent, rid, group)``: ``parent`` is the index of the innermost
+        span open when it began (None at the top).  Records nothing when
+        the registry is disabled, and counts what the bound
+        :data:`MAX_SPANS` turns away in ``obs_spans_dropped_total``."""
+        if not self.enabled or self._full():
+            return _NO_SPAN
+        return _Span(self, name, rid, group)
+
+    def _full(self) -> bool:
+        """True (and one more span counted as dropped) at the bound."""
+        if len(self._spans) < MAX_SPANS:
+            return False
+        self.counter("obs_spans_dropped_total",
+                     "spans past the registry's bound, not kept",
+                     domain="wall").inc()
+        return True
+
+    def _open_span(self, s: _Span) -> None:
+        s.parent = self._open[-1] if self._open else None
+        s.t0 = time.perf_counter_ns()
+        entry = (s.t0, None, s.name, s.parent, s.rid, s.group)
+        # the index is read after the append: building the entry may run
+        # a collection whose ``gc`` span lands first
+        self._spans.append(entry)
+        s.index = len(self._spans) - 1
+        self._open.append(s.index)
+
+    def _close_span(self, s: _Span) -> None:
+        t1 = time.perf_counter_ns()
+        if s.index not in self._open:          # cleared while open
+            return
+        self._open.remove(s.index)
+        self._spans[s.index] = (s.t0, t1, s.name, s.parent, s.rid, s.group)
+
+    def _add_span(self, t0: int, t1: int, name: str) -> None:
+        """Record a span that was timed elsewhere (a collection)."""
+        if not self.enabled or self._full():
+            return
+        self._spans.append((t0, t1, name,
+                            self._open[-1] if self._open else None,
+                            None, None))
+
+    def spans(self) -> list[tuple]:
+        """Every span recorded so far, in the order each began (``t1_ns``
+        is None while a span is open)."""
+        return list(self._spans)
+
+    def clear_spans(self) -> None:
+        """Forget the spans recorded so far; call it with no span open."""
+        self._spans.clear()
+        self._open.clear()
 
     # ------------------------------------------------------------------
     def _get(self, cls, name: str, help: str, domain: str, **kw):
@@ -230,6 +339,35 @@ class Registry:
     def sources(self) -> list[str]:
         """Names of remote registries absorbed so far."""
         return sorted(self._absorbed)
+
+
+def track_gc(registry: Registry):
+    """Record every garbage collection into ``registry``: a ``gc`` span
+    and an observation of the ``wall`` histogram
+    ``gc_pause_seconds{generation}``.  Installs one ``gc.callbacks``
+    hook; returns a function that removes it."""
+    started: list[int] = []
+
+    def on_gc(phase: str, info: dict) -> None:
+        if phase == "start":
+            started.append(time.perf_counter_ns())
+            return
+        if not started:                  # installed mid-collection
+            return
+        t0, t1 = started.pop(), time.perf_counter_ns()
+        registry._add_span(t0, t1, "gc")
+        registry.histogram("gc_pause_seconds",
+                           "garbage-collection pauses",
+                           domain="wall").observe(
+            (t1 - t0) * 1e-9, {"generation": info["generation"]})
+
+    gc.callbacks.append(on_gc)
+
+    def uninstall() -> None:
+        if on_gc in gc.callbacks:
+            gc.callbacks.remove(on_gc)
+
+    return uninstall
 
 
 def _merge_into(out: dict, snap: dict, domain: str | None) -> None:

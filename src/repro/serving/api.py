@@ -512,8 +512,9 @@ class EngineBase:
     between workloads.
     """
 
-    obs = None           # optional repro.obs.Registry (fleet wires it;
-    #                      standalone engines run uninstrumented)
+    obs = None           # optional repro.obs.Registry: when set, the
+    #                      engine records its spans into it (None: the
+    #                      hot path reads no clock for them)
 
     def __init__(self, *, max_queue: int | None = None):
         if max_queue is not None and max_queue < 1:
@@ -664,11 +665,16 @@ class EngineBase:
             self._t0 = time.perf_counter()
 
     def _finish(self, rid: int, output) -> Completion:
-        """Materialize ``output``, stamp the finish time, file the
+        """Materialize ``output`` (a ``request.materialize`` span when
+        :attr:`obs` is set), stamp the finish time, file the
         completion."""
         import jax
 
-        jax.block_until_ready(output)
+        if self.obs is None:
+            jax.block_until_ready(output)
+        else:
+            with self.obs.span("request.materialize", rid=rid):
+                jax.block_until_ready(output)
         m = self._metrics[rid]
         m.finished_at = time.perf_counter()
         pol = getattr(self, "policy", None)

@@ -116,8 +116,12 @@ class DualCoreEngine(EngineBase):
         (cross-core env hop included)."""
         gi = f.next_group
         h = self._handles[gi]
-        f.env = h(f.env, prev_core=self._handles[gi - 1].core
-                  if gi > 0 else None)
+        prev = self._handles[gi - 1].core if gi > 0 else None
+        if self.obs is None:
+            f.env = h(f.env, prev_core=prev)
+        else:
+            with self.obs.span("group.call", rid=f.rid, group=gi):
+                f.env = h(f.env, prev_core=prev)
         if self._record is not None:
             self._record.append((self._slot, f.rid, gi, h.core))
         f.next_group = gi + 1
@@ -144,7 +148,14 @@ class DualCoreEngine(EngineBase):
         own more dispatches for the same wall-clock window (the fleet's
         cross-engine co-dispatch) issue those first and call
         :meth:`retire` after — the same block-last rule ``step`` applies
-        within one engine, extended across engines."""
+        within one engine, extended across engines.  With :attr:`obs`
+        set, the whole phase is a ``slot.dispatch`` span."""
+        if self.obs is None:
+            return self._advance()
+        with self.obs.span("slot.dispatch"):
+            return self._advance()
+
+    def _advance(self) -> list["_Flight"]:
         self._start_clock()
         # 0. shed past-deadline queue entries (ShedPolicy only) against
         #    the engine's own slot counter — unless an external clock
@@ -166,14 +177,13 @@ class DualCoreEngine(EngineBase):
         n = max(0, min(n, 1, self.capacity - len(self._flight),
                        len(self._pending)))
         if n:
-            popped = self._pop_admission()      # None: everything left in
-            if popped is not None:              # the queue was shed
-                req, ticket = popped
-                self._metrics[req.rid].started_at = time.perf_counter()
-                f = _Flight(rid=req.rid,
-                            env=self.runner.place_input(req.payload),
-                            next_group=0, ticket=ticket,
-                            metrics=self._metrics[req.rid])
+            if self.obs is None:
+                f = self._admit()
+            else:
+                with self.obs.span("request.admit") as span:
+                    f = self._admit()
+                    span.rid = None if f is None else f.rid
+            if f is not None:
                 self._dispatch(f)
                 if f.next_group >= self.capacity:   # single-group chain
                     finished.append(f)
@@ -182,12 +192,31 @@ class DualCoreEngine(EngineBase):
         self._slot += 1
         return finished
 
+    def _admit(self) -> "_Flight | None":
+        """Pop the next request and place its input on group 0's core;
+        None when shedding emptied the queue."""
+        popped = self._pop_admission()
+        if popped is None:
+            return None
+        req, ticket = popped
+        self._metrics[req.rid].started_at = time.perf_counter()
+        return _Flight(rid=req.rid,
+                       env=self.runner.place_input(req.payload),
+                       next_group=0, ticket=ticket,
+                       metrics=self._metrics[req.rid])
+
     def retire(self, finished: list["_Flight"]) -> list[Completion]:
         """Materialize the outputs of flights returned by
         :meth:`advance` — only after every dispatch of the slot is in
         flight; blocking earlier would serialize the cross-core overlap.
         Shed completions buffered during the dispatch phase ride out
-        here too."""
+        here too.  With :attr:`obs` set, it is a ``slot.retire`` span."""
+        if self.obs is None:
+            return self._retire(finished)
+        with self.obs.span("slot.retire"):
+            return self._retire(finished)
+
+    def _retire(self, finished: list["_Flight"]) -> list[Completion]:
         out = self._take_shed()
         out.extend(self._finish(f.rid, f.env["out"]) for f in finished)
         return out

@@ -657,31 +657,25 @@ def test_chrome_trace_control_track_and_pool_row_order():
     assert {e["cat"] for e in control} == {"SET_PARAM", "REBALANCE"}
 
 
-def test_chrome_trace_roofline_args_clamped_and_bounded():
+def test_chrome_trace_run_slices_carry_advances_and_wall_window():
     recs = [
-        # 4 advances in 2 ms against a 10k fps roofline: util 0.2
         ExecRecord(instr=Run(member="a", slots=1, core="c"), slot=0,
-                   seq=0, advances=4, t0=0.0, t1=0.002),
-        # 50 advances in 1 ms = 50k fps achieved: clamps to 1.05
-        ExecRecord(instr=Run(member="a", slots=1, core="c"), slot=1,
-                   seq=1, advances=50, t0=0.002, t1=0.003),
-        # member without pricing: no roofline args
-        ExecRecord(instr=Run(member="b", slots=1, core="p"), slot=2,
-                   seq=2, advances=1, t0=0.003, t1=0.004),
+                   seq=0, advances=4, t0=10.0, t1=10.002),
+        ExecRecord(instr=Run(member="b", slots=1, core="p"), slot=1,
+                   seq=1, advances=1, t0=10.002, t1=10.003),
     ]
-    doc = chrome_trace({"p0": recs}, roofline={"p0": {"a": 10_000.0}})
+    doc = chrome_trace({"p0": recs})
     runs = [e for e in doc["traceEvents"]
             if e["ph"] == "X" and e["cat"] == "RUN"]
-    assert len(runs) == 3
-    priced = [e for e in runs if "roofline_util" in e["args"]]
-    assert len(priced) == 2
-    for e in priced:
-        assert 0 < e["args"]["roofline_util"] <= 1.05
-        assert e["args"]["achieved_fps"] > 0
-        assert e["args"]["roofline_fps"] == 10_000.0
-    assert priced[0]["args"]["roofline_util"] == pytest.approx(0.2)
-    assert priced[1]["args"]["roofline_util"] == 1.05
-    assert "roofline_util" not in runs[2]["args"]
+    assert [e["args"] for e in runs] == [
+        {"slot": 0, "seq": 0, "advances": 4},
+        {"slot": 1, "seq": 1, "advances": 1}]
+    # the wall window, in µs from the earliest stamp
+    assert runs[0]["ts"] == 0.0
+    assert runs[0]["dur"] == pytest.approx(2000.0)
+    assert runs[1]["ts"] == pytest.approx(2000.0)
+    assert runs[1]["dur"] == pytest.approx(1000.0)
+    assert [e["tid"] for e in runs] == [0, 1]
 
 
 def test_chrome_trace_bubble_events():

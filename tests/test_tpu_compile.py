@@ -11,6 +11,8 @@ The topology is described inside a module-scoped fixture, never at import
 time: only one process may load the TPU library, and under pytest-xdist
 every worker imports this file.  Keep these tests in this one file.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -86,3 +88,50 @@ def test_fused_dw_pw_compiles(one_chip):
                  interpret=False),
              one_chip, (1, 112, 112, 32), (3, 3, 32), (32,), (32, 64),
              (64,))
+
+
+# the device-op names the benchmark's roofline readers match
+KERNEL_OPS = {"matmul_bias_act", "conv2d_implicit_gemm", "depthwise_conv2d",
+              "fused_dw_pw_conv", "fused_pw_dw_pw_conv"}
+_CUSTOM_CALL = re.compile(
+    r"^\s*(?:ROOT )?%([\w-]+?)(?:\.\d+)* = .*custom-call\(", re.M)
+_INSTRUCTION = re.compile(r"^\s*(?:ROOT )?%([\w.-]+) = ", re.M)
+
+
+def test_exec_groups_compile_under_stable_names(one_chip, monkeypatch):
+    """Every mobilenet_v2 exec group at 224 px compiles to a program named
+    ``jit_dualcore_g<NN>_<core>``; its kernels keep the instruction names
+    the device trace is read by, and its instructions are named as when
+    the group compiled under its old name, ``group_fn``."""
+    import repro.kernels.util as kutil
+    from repro.core.arch import DUAL_BASELINE, BoardModel
+    from repro.core.scheduler import build_schedule
+    from repro.dualcore.runtime import DualCoreRunner
+    from repro.models.cnn import build_model
+
+    monkeypatch.setattr(kutil, "default_interpret", lambda: False)
+    params, _, g = build_model("mobilenet_v2")
+    runner = DualCoreRunner("mobilenet_v2", params, build_schedule(
+        g, DUAL_BASELINE, BoardModel(), "balanced"))
+
+    def on_chip(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    env = {"h": jax.ShapeDtypeStruct((1, 224, 224, 3), jnp.float32)}
+    for gi, (fn, group) in enumerate(zip(runner._fns, runner.groups)):
+        params = runner._params[group.core]
+        name = f"dualcore_g{gi:02d}_{group.core}"
+        text = fn.lower(on_chip(params), on_chip(env)).compile().as_text()
+        assert text.startswith(f"HloModule jit_{name},")
+        kernels = _CUSTOM_CALL.findall(text)
+        assert kernels and set(kernels) <= KERNEL_OPS, kernels
+        if gi < 2:                       # one group of each core
+
+            def group_fn(params, env, body=fn.__wrapped__):
+                return body(params, env)
+
+            old = jax.jit(group_fn).lower(on_chip(params), on_chip(env))
+            assert _INSTRUCTION.findall(old.compile().as_text()) == \
+                _INSTRUCTION.findall(text)
+        env = jax.eval_shape(fn, params, env)

@@ -16,7 +16,10 @@ split 2 c-chips + 2 p-chips; the c and p device sets must be disjoint and
 every exec group's output must live on its own submesh.
 
 Every phase checks that each exec-group program contains a Pallas kernel
-(``tpu_custom_call`` in its compiled HLO) and that every served output is
+(``tpu_custom_call`` in its compiled HLO), except a group whose every
+layer ``conv2d_gemm`` routes to XLA (``xla_routed``: a stem conv alone),
+which must hold an XLA ``convolution`` and no kernel; and that every
+served output is
 within ``TOL`` of a plain whole-model reference run on one chip: the XLA
 forward (``use_pallas=False``) under ``jax.default_matmul_precision(
 "highest")``.  Weights are random, from a seed; payloads are seeded.
@@ -112,28 +115,50 @@ def reference(model: str):
     return ref
 
 
+def xla_only(graph, group) -> bool:
+    """Whether every layer of an exec group is a conv that ``conv2d_gemm``
+    routes to XLA's convolution (``xla_routed``): such a group holds no
+    Pallas kernel."""
+    from repro.kernels.conv_gemm.ops import xla_routed
+
+    return all(l.op == "conv" and xla_routed(l.K_h, l.K_w, l.stride, l.pad)
+               for l in map(graph.layer, group.layers))
+
+
 def check_member(model: str, runner, x, *, require_kernels: bool) -> dict:
     """Exec groups of one member: their count, the c/p device ids, whether
-    each compiled group program holds a Pallas kernel, and whether each
-    group's output sits on its own core's submesh."""
+    each compiled group program holds a Pallas kernel (a group of
+    XLA-routed convs alone: an XLA convolution and no kernel), and whether
+    each group's output sits on its own core's submesh."""
     c_ids = sorted(d.id for d in runner.dual.c_mesh.devices.flat)
     p_ids = sorted(d.id for d in runner.dual.p_mesh.devices.flat)
-    kernels, placed = 0, 0
+    kernels, xla_convs, placed, wrong = 0, 0, 0, []
     for (compiled, env), group in zip(runner.trace_groups(x),
                                       runner.groups):
-        kernels += "tpu_custom_call" in compiled.as_text()
+        text = compiled.as_text()
+        has_kernel = "tpu_custom_call" in text
+        kernels += has_kernel
+        if xla_only(runner.graph, group):
+            xla_convs += 1
+            if has_kernel or " convolution(" not in text:
+                wrong.append(group.layers)
+        elif not has_kernel:
+            wrong.append(group.layers)
         want = set(c_ids if group.core == "c" else p_ids)
         placed += all({d.id for d in a.sharding.device_set} == want
                       for a in env.values())
     n = len(runner.groups)
-    if require_kernels and kernels != n:
-        raise SmokeError(f"{model}: {n - kernels} of {n} exec-group "
-                         f"programs hold no tpu_custom_call")
+    if require_kernels and wrong:
+        raise SmokeError(f"{model}: {len(wrong)} of {n} exec-group "
+                         f"programs miss their route (a tpu_custom_call, "
+                         f"or for XLA-routed convs alone a convolution "
+                         f"and no kernel): {wrong}")
     if placed != n:
         raise SmokeError(f"{model}: {n - placed} of {n} exec-group outputs "
                          f"are not on their core's submesh")
     return {"model": model, "exec_groups": n, "c_devices": c_ids,
-            "p_devices": p_ids, "groups_with_tpu_custom_call": kernels}
+            "p_devices": p_ids, "groups_with_tpu_custom_call": kernels,
+            "groups_xla_routed": xla_convs}
 
 
 def run_phase(label: str, argv: list[str], *, image_size: int = IMAGE,

@@ -10,7 +10,7 @@ import pytest
 from repro.core.fusion import fused_layer_counts, plan_fusion
 from repro.core.graph import LayerSpec
 from repro.kernels import autotune
-from repro.kernels.conv_gemm.ops import conv2d_gemm
+from repro.kernels.conv_gemm.ops import conv2d_gemm, implicit_gemm_conv
 from repro.kernels.conv_gemm.ref import conv2d_ref
 from repro.kernels.fused_block.kernel import (fused_dw_pw_conv,
                                               fused_pw_dw_pw_conv)
@@ -113,8 +113,11 @@ def _zoo_conv_sigs():
 
 @pytest.mark.parametrize("h,w,ci,co,kh,kw,s,p", _zoo_conv_sigs())
 def test_implicit_gemm_zoo_layer(h, w, ci, co, kh, kw, s, p):
-    """Acceptance: implicit-GEMM conv matches conv2d_ref to 1e-4 on every
-    conv layer in the model zoo."""
+    """Acceptance: ``conv2d_gemm`` matches conv2d_ref to 1e-4 on every
+    conv layer in the model zoo, on the route it takes there: the tiled
+    GEMM for 1x1 convs, XLA's convolution for every other conv
+    (``xla_routed``).  The implicit-GEMM kernel's own cases are
+    ``test_implicit_gemm_kernel_matches_ref``."""
     x = rand(KEYS[0], (1, h, w, ci), 0.5)
     wgt = rand(KEYS[1], (kh, kw, ci, co), 0.2)
     b = rand(KEYS[2], (co,), 0.1)
@@ -124,9 +127,7 @@ def test_implicit_gemm_zoo_layer(h, w, ci, co, kh, kw, s, p):
                                rtol=1e-4, atol=1e-4)
 
 
-def test_implicit_gemm_never_materializes_patch_matrix():
-    """Acceptance: no (N*Ho*Wo, Kh*Kw*C) intermediate anywhere in the
-    jaxpr of the conv path."""
+def _assert_no_patch_matrix(conv):
     n, h, ci, co, k, s, p = 1, 28, 32, 64, 3, 1, 1
     ho = (h + 2 * p - k) // s + 1
     forbidden = {(n * ho * ho, k * k * ci)}
@@ -134,7 +135,7 @@ def test_implicit_gemm_never_materializes_patch_matrix():
     x = jnp.zeros((n, h, h, ci))
     w = jnp.zeros((k, k, ci, co))
     jaxpr = jax.make_jaxpr(
-        lambda a, b: conv2d_gemm(a, b, stride=s, pad=p))(x, w)
+        lambda a, b: conv(a, b, stride=s, pad=p))(x, w)
 
     def walk(jx):
         for eqn in jx.eqns:
@@ -150,6 +151,18 @@ def test_implicit_gemm_never_materializes_patch_matrix():
                     walk(sub.jaxpr)
 
     walk(jaxpr.jaxpr)
+
+
+def test_implicit_gemm_never_materializes_patch_matrix():
+    """Acceptance: no (N*Ho*Wo, Kh*Kw*C) intermediate anywhere in the
+    jaxpr of the conv path."""
+    _assert_no_patch_matrix(conv2d_gemm)
+
+
+def test_implicit_gemm_kernel_never_materializes_patch_matrix():
+    """Nor in the implicit-GEMM kernel's, called directly (``conv2d_gemm``
+    runs XLA's convolution for a 3x3 conv)."""
+    _assert_no_patch_matrix(implicit_gemm_conv)
 
 
 # --------------------------------------------------------------------------

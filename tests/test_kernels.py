@@ -8,7 +8,8 @@ import pytest
 from hypothesis import given, settings, strategies as st
 
 from repro.kernels.conv_gemm.kernel import matmul_bias_act
-from repro.kernels.conv_gemm.ops import conv2d_gemm, pointwise_conv
+from repro.kernels.conv_gemm.ops import (conv2d_gemm, implicit_gemm_conv,
+                                         pointwise_conv, xla_routed)
 from repro.kernels.conv_gemm.ref import conv2d_ref, matmul_bias_act_ref
 from repro.kernels.depthwise.ops import depthwise
 from repro.kernels.depthwise.ref import depthwise_conv2d_ref
@@ -16,6 +17,7 @@ from repro.kernels.attention.kernel import flash_attention
 from repro.kernels.attention.ref import attention_ref
 from repro.kernels.rmsnorm.kernel import rmsnorm
 from repro.kernels.rmsnorm.ref import rmsnorm_ref
+from repro.models.zoo import get_graph
 
 
 def tol(dtype):
@@ -62,7 +64,7 @@ def test_matmul_property(m, k, n, act):
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 @pytest.mark.parametrize("h,ci,co,k,s,pad", [
     (14, 32, 64, 3, 1, 1), (28, 16, 24, 3, 2, 1),
-    (8, 8, 16, 1, 1, 0), (224 // 8, 3, 32, 3, 2, 1)])
+    (8, 8, 16, 1, 1, 0), (224 // 8, 3, 32, 3, 2, 1), (7, 128, 32, 3, 1, 1)])
 def test_conv2d_gemm(h, ci, co, k, s, pad, dtype):
     x = rand(KEYS[0], (2, h, h, ci), dtype, 0.5)
     w = rand(KEYS[1], (k, k, ci, co), dtype, 0.2)
@@ -79,6 +81,117 @@ def test_pointwise_matches_conv():
     np.testing.assert_allclose(pointwise_conv(x, w),
                                conv2d_ref(x, w, stride=1, pad=0),
                                rtol=3e-4, atol=3e-4)
+
+
+def _eqns(jaxpr):
+    """Every equation of a jaxpr, those of nested jaxprs included."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in eqn.params.values():
+            sub = getattr(sub, "jaxpr", sub)
+            if hasattr(sub, "eqns"):
+                yield from _eqns(sub)
+
+
+def _conv_route(x, w, b, stride, pad) -> str:
+    """Which implementation ``conv2d_gemm`` traces for these shapes.
+    ``interpret`` is explicit: a kernel traced with ``interpret=None``
+    caches the interpret-mode body under that key for the process."""
+    jaxpr = jax.make_jaxpr(lambda a, k, c: conv2d_gemm(
+        a, k, c, stride=stride, pad=pad, act="relu", interpret=True))(
+            x, w, b)
+    eqns = list(_eqns(jaxpr.jaxpr))
+    prims = {e.primitive.name for e in eqns}
+    if "conv_general_dilated" in prims:
+        assert "pallas_call" not in prims, prims
+        return "xla"
+    names = {e.params.get("name") for e in eqns}
+    for kernel in ("conv2d_implicit_gemm", "matmul_bias_act"):
+        if kernel in names:
+            return kernel
+    raise AssertionError(f"no conv route in {sorted(prims)}")
+
+
+@pytest.mark.parametrize("model", ["mobilenet_v1", "mobilenet_v2",
+                                   "squeezenet"])
+def test_conv_route_table(model):
+    """Every zoo conv off the plain 1x1 path (the RGB stems, the fire
+    expands) takes XLA's convolution, and every 1x1 conv the tiled GEMM;
+    no zoo layer reaches the implicit-GEMM kernel."""
+    routes = {}
+    for l in get_graph(model).layers:
+        if l.op != "conv":
+            continue
+        x = jax.ShapeDtypeStruct((1, l.H, l.W, l.C_i), jnp.float32)
+        w = jax.ShapeDtypeStruct((l.K_h, l.K_w, l.C_i, l.C_o), jnp.float32)
+        b = jax.ShapeDtypeStruct((l.C_o,), jnp.float32)
+        routes[l.name] = _conv_route(x, w, b, l.stride, l.pad)
+    want = {"conv1"} | {n for n in routes if n.endswith("_e3x3")}
+    assert {n for n, r in routes.items() if r == "xla"} == want
+    for name, route in routes.items():
+        if name not in want:
+            assert route == "matmul_bias_act", (name, route)
+
+
+@pytest.mark.parametrize("kh,ci,stride,pad,routed", [
+    (3, 3, 2, 1, True),            # the RGB stem
+    (3, 64, 1, 1, True),           # squeezenet fire8/9 e3x3
+    (3, 128, 1, 1, True),          # lane-full 3x3
+    (1, 64, 2, 0, True),           # strided 1x1
+    (1, 128, 1, 1, True),          # padded 1x1, lane-full
+    (1, 3, 1, 0, False)])          # plain 1x1: the tiled GEMM
+def test_xla_route_rule(kh, ci, stride, pad, routed):
+    """``xla_routed`` decides the route, and ``conv2d_gemm`` follows it."""
+    assert xla_routed(kh, kh, stride, pad) is routed
+    x = jax.ShapeDtypeStruct((1, 16, 16, ci), jnp.float32)
+    w = jax.ShapeDtypeStruct((kh, kh, ci, 32), jnp.float32)
+    b = jax.ShapeDtypeStruct((32,), jnp.float32)
+    assert _conv_route(x, w, b, stride, pad) == (
+        "xla" if routed else "matmul_bias_act")
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("h,ci,co,s", [
+    (28, 3, 32, 2),                # the stem's shape, at 28 px
+    (14, 32, 64, 1),
+    (8, 128, 64, 1)])              # lane-full
+def test_implicit_gemm_kernel_matches_ref(h, ci, co, s, dtype):
+    """The implicit-GEMM kernel called directly (``conv2d_gemm`` sends
+    these convs to XLA): the stem's shape, a fire-like one, lane-full."""
+    x = rand(KEYS[0], (2, h, h, ci), dtype, 0.5)
+    w = rand(KEYS[1], (3, 3, ci, co), dtype, 0.2)
+    b = rand(KEYS[2], (co,), dtype)
+    out = implicit_gemm_conv(x, w, b, stride=s, pad=1, act="relu6")
+    ref = conv2d_ref(x, w, b, stride=s, pad=1, act="relu6")
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(ref, np.float32), **tol(dtype))
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("with_bias", [True, False])
+def test_stem_route_is_one_xla_conv(dtype, with_bias):
+    """The stem route calls no kernel and pads nothing to 128 lanes: one
+    ``conv_general_dilated`` (XLA pads the window inside it), with
+    conv2d_ref's answer."""
+    x = rand(KEYS[0], (2, 32, 32, 3), dtype, 0.5)
+    w = rand(KEYS[1], (3, 3, 3, 32), dtype, 0.2)
+    b = rand(KEYS[2], (32,), dtype) if with_bias else None
+
+    def stem(a, k, c):
+        return conv2d_gemm(a, k, c, stride=2, pad=1, act="relu6")
+
+    eqns = list(_eqns(jax.make_jaxpr(stem)(x, w, b).jaxpr))
+    prims = [e.primitive.name for e in eqns]
+    assert prims.count("conv_general_dilated") == 1, prims
+    assert "pallas_call" not in prims and "pad" not in prims, prims
+    assert not any(v.aval.shape[-1:] == (128,)
+                   for e in eqns for v in e.outvars)
+    out = stem(x, w, b)
+    assert out.dtype == dtype
+    np.testing.assert_allclose(
+        np.asarray(out, np.float32),
+        np.asarray(conv2d_ref(x, w, b, stride=2, pad=1, act="relu6"),
+                   np.float32), **tol(dtype))
 
 
 # --------------------------------------------------------------------------

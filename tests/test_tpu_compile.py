@@ -5,7 +5,9 @@ refuses: dynamic slices of loaded values, strided slices, strided reads
 wider than one lane tile, blocks that are not lane-aligned, tiles that
 overflow VMEM.  Each test here compiles one kernel for a *described* v5e
 chip — no chip attached — and checks the program holds the Pallas kernel
-(``tpu_custom_call``).  Nothing runs, so nothing here is a time.
+(``tpu_custom_call``), or, for a conv that ``conv2d_gemm`` routes to XLA
+(``xla_routed``: every conv but a plain 1x1), a ``convolution`` and no
+kernel.  Nothing runs, so nothing here is a time.
 
 The topology is described inside a module-scoped fixture, never at import
 time: only one process may load the TPU library, and under pytest-xdist
@@ -18,7 +20,8 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.kernels.conv_gemm.ops import conv2d_gemm
+from repro.kernels.conv_gemm.ops import (conv2d_gemm, implicit_gemm_conv,
+                                         xla_routed)
 from repro.kernels.depthwise.ops import depthwise
 from repro.kernels.fused_block.ops import fused_dw_pw, fused_inverted_residual
 
@@ -45,19 +48,51 @@ def one_chip():
     jax.config.update("jax_enable_compilation_cache", was)
 
 
-def _compile(fn, sharding, *shapes):
+def _hlo(fn, sharding, *shapes) -> str:
     args = [jax.ShapeDtypeStruct(s, jnp.float32, sharding=sharding)
             for s in shapes]
-    compiled = jax.jit(fn).lower(*args).compile()
-    assert "tpu_custom_call" in compiled.as_text()
-    return compiled
+    return jax.jit(fn).lower(*args).compile().as_text()
 
 
-# (x shape, w shape, stride): the 3x3 s2 stem of all three zoo models
+def _compile(fn, sharding, *shapes):
+    assert "tpu_custom_call" in _hlo(fn, sharding, *shapes)
+
+
+# an XLA convolution in HLO text: ``%name = f32[...] convolution(...)``
+XLA_CONV = " convolution("
+
+
+def _compiles_to_xla_conv(sharding, xs, ws, stride):
+    text = _hlo(lambda x, w, b: conv2d_gemm(x, w, b, stride=stride, pad=1,
+                                            act="relu6", interpret=False),
+                sharding, xs, ws, ws[-1:])
+    assert XLA_CONV in text and "tpu_custom_call" not in text
+
+
 def test_stem_conv_compiles(one_chip):
-    _compile(lambda x, w, b: conv2d_gemm(x, w, b, stride=2, pad=1,
-                                         act="relu6", interpret=False),
-             one_chip, (1, 224, 224, 3), (3, 3, 3, 32), (32,))
+    """The 3x3 s2 stem of all three zoo models compiles to XLA's
+    convolution (``xla_routed``), with no Pallas kernel."""
+    _compiles_to_xla_conv(one_chip, (1, 224, 224, 3), (3, 3, 3, 32), 2)
+
+
+@pytest.mark.parametrize("xs,ws", [
+    ((1, 56, 56, 16), (3, 3, 16, 64)),          # squeezenet fire2_e3x3
+    ((1, 14, 14, 128), (3, 3, 128, 128))])      # lane-full, no zoo layer
+def test_xla_routed_conv_compiles(one_chip, xs, ws):
+    """Every other conv off the plain 1x1 path does too."""
+    _compiles_to_xla_conv(one_chip, xs, ws, 1)
+
+
+# squeezenet fire2_e3x3 (16 -> 64 channels, 56 px) and a lane-full 3x3 conv
+@pytest.mark.parametrize("xs,ws", [
+    ((1, 56, 56, 16), (3, 3, 16, 64)),
+    ((1, 14, 14, 128), (3, 3, 128, 128))])
+def test_implicit_gemm_conv_compiles(one_chip, xs, ws):
+    """The implicit-GEMM kernel, called directly (``conv2d_gemm`` no
+    longer runs it), still compiles at 3x3 s1."""
+    _compile(lambda x, w, b: implicit_gemm_conv(
+        x, w, b, stride=1, pad=1, act="relu", interpret=False),
+        one_chip, xs, ws, ws[-1:])
 
 
 @pytest.mark.parametrize("shape,stride", [
@@ -101,7 +136,8 @@ _INSTRUCTION = re.compile(r"^\s*(?:ROOT )?%([\w.-]+) = ", re.M)
 def test_exec_groups_compile_under_stable_names(one_chip, monkeypatch):
     """Every mobilenet_v2 exec group at 224 px compiles to a program named
     ``jit_dualcore_g<NN>_<core>``; its kernels keep the instruction names
-    the device trace is read by, and its instructions are named as when
+    the device trace is read by (a group of XLA-routed layers alone holds
+    a convolution and no kernel), and its instructions are named as when
     the group compiled under its old name, ``group_fn``."""
     import repro.kernels.util as kutil
     from repro.core.arch import DUAL_BASELINE, BoardModel
@@ -110,6 +146,9 @@ def test_exec_groups_compile_under_stable_names(one_chip, monkeypatch):
     from repro.models.cnn import build_model
 
     monkeypatch.setattr(kutil, "default_interpret", lambda: False)
+    # the kernels resolve ``interpret=None`` while tracing, so a trace an
+    # earlier test cached for the same shapes is an interpret-mode body
+    jax.clear_caches()
     params, _, g = build_model("mobilenet_v2")
     runner = DualCoreRunner("mobilenet_v2", params, build_schedule(
         g, DUAL_BASELINE, BoardModel(), "balanced"))
@@ -125,7 +164,11 @@ def test_exec_groups_compile_under_stable_names(one_chip, monkeypatch):
         text = fn.lower(on_chip(params), on_chip(env)).compile().as_text()
         assert text.startswith(f"HloModule jit_{name},")
         kernels = _CUSTOM_CALL.findall(text)
-        assert kernels and set(kernels) <= KERNEL_OPS, kernels
+        if all(l.op == "conv" and xla_routed(l.K_h, l.K_w, l.stride, l.pad)
+               for l in map(g.layer, group.layers)):
+            assert XLA_CONV in text and not kernels, kernels
+        else:
+            assert kernels and set(kernels) <= KERNEL_OPS, kernels
         if gi < 2:                       # one group of each core
 
             def group_fn(params, env, body=fn.__wrapped__):

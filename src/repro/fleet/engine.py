@@ -61,9 +61,17 @@ Per-request metrics are accounted at the fleet boundary: latency runs
 from fleet submit to member completion, tagged with the model, so
 ``result().metrics.by_model()`` gives the per-network p50/p95 next to the
 aggregate.
+
+With :attr:`FleetEngine.obs` set to a ``repro.obs.Registry`` (it hands
+the registry to every member too), each slot is a ``fleet.step`` span
+holding a ``fleet.lower`` span (the members' views, the policy and the
+lowering) and a ``fleet.execute`` span (the executor's RUNs and FREEs,
+with the members' ``slot.dispatch``/``group.call``/``slot.retire`` spans
+inside, each carrying its ``model``).
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import time
 from typing import Mapping, Sequence
@@ -151,6 +159,20 @@ class FleetEngine(EngineBase):
         # inject SET_PARAM/REBALANCE into the recorded stream, so a
         # controlled run replays with no controller attached (§13)
         self.controller = None
+        self._obs = None
+
+    # ------------------------------------------------------------------
+    @property
+    def obs(self):
+        """The ``repro.obs.Registry`` the fleet and its members record
+        their spans into (None: no spans, no clock read for them)."""
+        return self._obs
+
+    @obs.setter
+    def obs(self, registry) -> None:
+        self._obs = registry
+        for m in self.members:
+            m.engine.obs = registry
 
     # ------------------------------------------------------------------
     @property
@@ -242,14 +264,25 @@ class FleetEngine(EngineBase):
         stream accumulates on :attr:`stream`; a stream compiled ahead of
         time for the same arrivals replays to the same trace bitwise
         (``compiler.compile_fleet``, tested)."""
+        obs = self._obs
+        if obs is None:
+            return self._step(None)
+        with obs.span("fleet.step"):
+            return self._step(obs)
+
+    def _step(self, obs) -> list[Completion]:
+        span = _no_span if obs is None else obs.span
         self._start_clock()
-        views = self._views()
-        if not views:
-            return []
-        compiler = SlotCompiler(self.policy, co_dispatch=self.co_dispatch,
-                                burst=self.burst)
-        instrs = compiler.lower_slot(views, self._dispatches)
-        done = self.executor.execute_slot(instrs, self._slot)
+        with span("fleet.lower"):
+            views = self._views()
+            if not views:
+                return []
+            compiler = SlotCompiler(self.policy,
+                                    co_dispatch=self.co_dispatch,
+                                    burst=self.burst)
+            instrs = compiler.lower_slot(views, self._dispatches)
+        with span("fleet.execute"):
+            done = self.executor.execute_slot(instrs, self._slot)
         self._slot += 1
         if self.controller is not None:
             self.controller.on_slot(done)
@@ -330,6 +363,11 @@ class FleetEngine(EngineBase):
         return out
 
 
+def _no_span(name: str):
+    """What :meth:`FleetEngine.step` opens with no registry set."""
+    return contextlib.nullcontext()
+
+
 # --------------------------------------------------------------------------
 # fleet assembly
 # --------------------------------------------------------------------------
@@ -347,6 +385,8 @@ def build_cnn_fleet(models: Sequence[str], *,
                     max_queue: int | None = None,
                     co_dispatch: int | None = None,
                     burst: int = 1,
+                    params: Mapping[str, dict] | None = None,
+                    record: list | None = None,
                     ) -> tuple[FleetEngine, DevicePool]:
     """Stand up a CNN fleet: one shared :class:`DevicePool`, one
     ``DualCoreRunner`` + ``DualCoreEngine`` per model (each leasing the
@@ -355,14 +395,25 @@ def build_cnn_fleet(models: Sequence[str], *,
     ``plan`` (a ``fleet.planner.FleetPlan``) supplies the co-scheduled
     PE config, per-model schedules and mix weights; without one, every
     model is scheduled under ``DUAL_BASELINE`` with ``scheme``
-    (``"best"`` runs the full §V-A flow per model).
+    (``"best"`` runs the full §V-A flow per model).  ``params`` maps
+    every model to the weights it serves (``{layer: {"w", "b"}}``, as
+    ``models.cnn.init_params`` lays them out); without it each model
+    gets ``build_model``'s seeded He weights.  ``record``, when given, is
+    handed to every member's ``DualCoreEngine`` as its ``record``: each
+    exec-group dispatch of any member appends ``(slot, rid, group,
+    core)`` to it (slot and rid count per member).
     """
     from repro.core.arch import BoardModel, DUAL_BASELINE
     from repro.core.scheduler import best_schedule, build_schedule
     from repro.dualcore.runtime import DualCoreRunner
     from repro.models.cnn import build_model
+    from repro.models.zoo import get_graph
     from repro.serving.cnn import DualCoreEngine
 
+    if params is not None and set(params) != set(models):
+        raise ValueError(f"params are for {sorted(params)}, but the fleet "
+                         f"serves {sorted(models)}: give every member's "
+                         f"weights, or none")
     board = BoardModel()
     if pool is None:
         # a plan's theta is part of the planned configuration — the pool
@@ -378,7 +429,10 @@ def build_cnn_fleet(models: Sequence[str], *,
         weights = plan.mix
     members: dict[str, DualCoreEngine] = {}
     for model in models:
-        params, _, graph = build_model(model)
+        if params is None:
+            model_params, _, graph = build_model(model)
+        else:
+            model_params, graph = params[model], get_graph(model)
         if plan is not None:
             cfg = plan.config
             sched = plan.schedules[model]
@@ -387,11 +441,12 @@ def build_cnn_fleet(models: Sequence[str], *,
             sched = (best_schedule(graph, cfg, board)
                      if scheme == "best"
                      else build_schedule(graph, cfg, board, scheme))
-        runner = DualCoreRunner(model, params, sched,
+        runner = DualCoreRunner(model, model_params, sched,
                                 devices=pool.lease(model),
                                 use_pallas=use_pallas, fuse=fuse,
                                 jit_groups=jit_groups)
-        members[model] = DualCoreEngine(runner, max_queue=max_queue)
+        members[model] = DualCoreEngine(runner, max_queue=max_queue,
+                                        record=record)
     engine = FleetEngine(members, policy=policy, weights=weights,
                          admission=admission, co_dispatch=co_dispatch,
                          burst=burst, pool=pool)

@@ -294,10 +294,18 @@ class PoolExecutor:
                      slot: int) -> list[Completion]:
         """Execute one slot's instructions in order.  The compiler's
         RUN-before-FREE ordering is what preserves the block-last rule;
-        the executor does not re-sort."""
+        the executor does not re-sort.  The slot's RUNs (the members
+        dispatched into it) are observed in ``fleet_members_per_slot``."""
         done: list[Completion] = []
         for instr in instrs:
             done.extend(self.execute(instr, slot))
+        if self.obs.enabled:
+            self.obs.histogram(
+                "fleet_members_per_slot",
+                "members dispatched into one executed fleet slot", "wall",
+                bounds=DEFAULT_COUNT_BOUNDS).observe(
+                sum(isinstance(i, Run) for i in instrs),
+                labels={"pool": self.name})
         return done
 
     def inject(self, instr: Instruction) -> list[Completion]:

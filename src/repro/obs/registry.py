@@ -29,9 +29,9 @@ across processes (:meth:`Registry.absorb`), and exports
 
 Spans (:meth:`Registry.span`) are ``wall`` data kept apart from the
 metrics: an in-memory list of ``(t0_ns, t1_ns, name, parent, rid,
-group)`` on ``time.perf_counter_ns``, read by :meth:`Registry.spans` and
-never part of a snapshot.  :func:`track_gc` adds every garbage
-collection to them as a ``gc`` span.
+group, model)`` on ``time.perf_counter_ns``, read by
+:meth:`Registry.spans` and never part of a snapshot.  :func:`track_gc`
+adds every garbage collection to them as a ``gc`` span.
 """
 from __future__ import annotations
 
@@ -159,12 +159,12 @@ class _Span:
     """One open span of :meth:`Registry.span`; ``rid`` and ``group`` may
     be set inside the ``with`` block, once they are known."""
 
-    __slots__ = ("registry", "name", "rid", "group", "parent", "index",
-                 "t0")
+    __slots__ = ("registry", "name", "rid", "group", "model", "parent",
+                 "index", "t0")
 
-    def __init__(self, registry: "Registry", name: str, rid, group):
+    def __init__(self, registry: "Registry", name: str, rid, group, model):
         self.registry, self.name = registry, name
-        self.rid, self.group = rid, group
+        self.rid, self.group, self.model = rid, group, model
 
     def __enter__(self) -> "_Span":
         self.registry._open_span(self)
@@ -178,7 +178,7 @@ class _NoSpan:
     """What :meth:`Registry.span` hands out when it records nothing."""
 
     __slots__ = ()
-    rid = group = None
+    rid = group = model = None
 
     def __enter__(self) -> "_NoSpan":
         return self
@@ -211,15 +211,16 @@ class Registry:
 
     # ------------------------------------------------------------------
     def span(self, name: str, *, rid: int | None = None,
-             group: int | None = None):
+             group: int | None = None, model: str | None = None):
         """A context manager that records one span ``(t0_ns, t1_ns, name,
-        parent, rid, group)``: ``parent`` is the index of the innermost
-        span open when it began (None at the top).  Records nothing when
+        parent, rid, group, model)``: ``parent`` is the index of the
+        innermost span open when it began (None at the top); ``model``
+        names the network a fleet member serves.  Records nothing when
         the registry is disabled, and counts what the bound
         :data:`MAX_SPANS` turns away in ``obs_spans_dropped_total``."""
         if not self.enabled or self._full():
             return _NO_SPAN
-        return _Span(self, name, rid, group)
+        return _Span(self, name, rid, group, model)
 
     def _full(self) -> bool:
         """True (and one more span counted as dropped) at the bound."""
@@ -233,7 +234,7 @@ class Registry:
     def _open_span(self, s: _Span) -> None:
         s.parent = self._open[-1] if self._open else None
         s.t0 = time.perf_counter_ns()
-        entry = (s.t0, None, s.name, s.parent, s.rid, s.group)
+        entry = (s.t0, None, s.name, s.parent, s.rid, s.group, s.model)
         # the index is read after the append: building the entry may run
         # a collection whose ``gc`` span lands first
         self._spans.append(entry)
@@ -245,7 +246,8 @@ class Registry:
         if s.index not in self._open:          # cleared while open
             return
         self._open.remove(s.index)
-        self._spans[s.index] = (s.t0, t1, s.name, s.parent, s.rid, s.group)
+        self._spans[s.index] = (s.t0, t1, s.name, s.parent, s.rid, s.group,
+                                s.model)
 
     def _add_span(self, t0: int, t1: int, name: str) -> None:
         """Record a span that was timed elsewhere (a collection)."""
@@ -253,7 +255,7 @@ class Registry:
             return
         self._spans.append((t0, t1, name,
                             self._open[-1] if self._open else None,
-                            None, None))
+                            None, None, None))
 
     def spans(self) -> list[tuple]:
         """Every span recorded so far, in the order each began (``t1_ns``

@@ -515,6 +515,8 @@ class EngineBase:
     obs = None           # optional repro.obs.Registry: when set, the
     #                      engine records its spans into it (None: the
     #                      hot path reads no clock for them)
+    model = None         # the network the engine serves, where it serves
+    #                      one: its spans carry it
 
     def __init__(self, *, max_queue: int | None = None):
         if max_queue is not None and max_queue < 1:
@@ -673,7 +675,8 @@ class EngineBase:
         if self.obs is None:
             jax.block_until_ready(output)
         else:
-            with self.obs.span("request.materialize", rid=rid):
+            with self.obs.span("request.materialize", rid=rid,
+                               model=self.model):
                 jax.block_until_ready(output)
         m = self._metrics[rid]
         m.finished_at = time.perf_counter()

@@ -58,6 +58,7 @@ class DualCoreEngine(EngineBase):
     ``record``, when given, receives ``(slot, rid, group, core)`` tuples in
     dispatch order — the same trace ``run_pipelined`` produced, now with
     admission slots determined online by arrivals instead of statically.
+    The engine's spans carry the runner's network as ``model``.
     """
 
     def __init__(self, runner: "DualCoreRunner", *,
@@ -66,6 +67,7 @@ class DualCoreEngine(EngineBase):
                  record: list | None = None):
         super().__init__(max_queue=max_queue)
         self.runner = runner
+        self.model = runner.graph.name
         self.policy = policy or FixedRateAdmission(1)
         self.capacity = len(runner.groups)
         self._handles = runner.handles
@@ -120,7 +122,8 @@ class DualCoreEngine(EngineBase):
         if self.obs is None:
             f.env = h(f.env, prev_core=prev)
         else:
-            with self.obs.span("group.call", rid=f.rid, group=gi):
+            with self.obs.span("group.call", rid=f.rid, group=gi,
+                               model=self.model):
                 f.env = h(f.env, prev_core=prev)
         if self._record is not None:
             self._record.append((self._slot, f.rid, gi, h.core))
@@ -152,7 +155,7 @@ class DualCoreEngine(EngineBase):
         set, the whole phase is a ``slot.dispatch`` span."""
         if self.obs is None:
             return self._advance()
-        with self.obs.span("slot.dispatch"):
+        with self.obs.span("slot.dispatch", model=self.model):
             return self._advance()
 
     def _advance(self) -> list["_Flight"]:
@@ -180,7 +183,8 @@ class DualCoreEngine(EngineBase):
             if self.obs is None:
                 f = self._admit()
             else:
-                with self.obs.span("request.admit") as span:
+                with self.obs.span("request.admit",
+                                   model=self.model) as span:
                     f = self._admit()
                     span.rid = None if f is None else f.rid
             if f is not None:
@@ -213,7 +217,7 @@ class DualCoreEngine(EngineBase):
         here too.  With :attr:`obs` set, it is a ``slot.retire`` span."""
         if self.obs is None:
             return self._retire(finished)
-        with self.obs.span("slot.retire"):
+        with self.obs.span("slot.retire", model=self.model):
             return self._retire(finished)
 
     def _retire(self, finished: list["_Flight"]) -> list[Completion]:

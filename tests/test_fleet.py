@@ -436,6 +436,50 @@ def test_real_engines_co_dispatch_on_shared_pool():
     assert res.metrics.completed == 4
 
 
+def test_build_cnn_fleet_serves_the_given_params():
+    """``params=`` replaces ``build_model``'s weights for every member:
+    each output is bitwise what a standalone engine computes with those
+    weights (and not what the default weights give), and a map that
+    leaves a member out is refused."""
+    from repro.core.arch import BoardModel, DUAL_BASELINE
+    from repro.core.scheduler import build_schedule
+    from repro.dualcore.runtime import DualCoreRunner
+    from repro.models.cnn import init_params
+    from repro.models.zoo import get_graph
+    from repro.serving import stream_images
+
+    models = ["mobilenet_v1", "squeezenet"]
+    params = {m: jax.tree.map(lambda a: a + 0.01, init_params(
+        get_graph(m), jax.random.PRNGKey(10 + i)))
+        for i, m in enumerate(models)}
+    tags = mix_schedule({m: 0.5 for m in models}, 4)
+    imgs = [jax.random.normal(k, (1, 32, 32, 3))
+            for k in jax.random.split(jax.random.PRNGKey(2), 4)]
+
+    def serve(**kw):
+        eng, _ = build_cnn_fleet(models, use_pallas=False, fuse=False, **kw)
+        for x, t in zip(imgs, tags):
+            eng.submit(Request(x, model=t))
+        return [np.asarray(y) for y in eng.drain().outputs]
+
+    given, default = serve(params=params), serve()
+    for m in models:
+        sched = build_schedule(get_graph(m), DUAL_BASELINE, BoardModel(),
+                               "balanced")
+        runner = DualCoreRunner(m, params[m], sched, use_pallas=False,
+                                fuse=False)
+        mine = [x for x, t in zip(imgs, tags) if t == m]
+        want = stream_images(runner, mine).outputs
+        got = [y for y, t in zip(given, tags) if t == m]
+        other = [y for y, t in zip(default, tags) if t == m]
+        for g, w, o in zip(got, want, other):
+            np.testing.assert_array_equal(g, np.asarray(w))
+            assert not np.array_equal(g, o)
+    with pytest.raises(ValueError, match="every member"):
+        build_cnn_fleet(models, use_pallas=False, fuse=False,
+                        params={"squeezenet": params["squeezenet"]})
+
+
 @pytest.mark.slow
 def test_fleet_with_lm_member():
     """LM + CNN mix: a DualMeshEngine rides alongside a DualCoreEngine

@@ -3,17 +3,20 @@ reuse (the TPU port of the paper's line buffer, DESIGN.md §2).
 
 The dual-OPU p-core keeps a T_w*(T_kh-1)+T_kw line buffer in BRAM so each ifm
 pixel is read from DRAM once and reused across the K_h x K_w window.  On TPU
-the analogue is: bring a (H+K-1, W+K-1, block_c) halo tile into VMEM once and
-compute every window tap from it — HBM traffic is 1x the ifm instead of
+the analogue is: bring the halo rows of an output-row tile into VMEM once and
+compute every window tap from them — HBM traffic is 1x the ifm instead of
 K_h*K_w x.  Channel parallelism maps to the VPU lanes (channels-last, so the
-per-tap multiply is a (Ho, Wo, block_c) vector op), mirroring the p-core's
+per-tap multiply is a (bh, Wo, 128) vector op), mirroring the p-core's
 per-PE-per-channel layout.
 
-Grid: (N, C / block_c, H_out tiles).  Each step DMAs only the halo rows
-its output-row block reads (an element-indexed block; tiles overlap by
-K_h - 1 rows), so VMEM use is bounded whatever the map size, and reads
-every tap from that tile with ``util.window_tap`` (stride-2 columns are
-phase-split in the wrapper; the TPU compiler refuses strided slices).
+Grid: (N, C / block_c, H_out tiles).  The kernel takes the map unpadded:
+each step DMAs only the map rows its output-row block reads (an
+element-indexed block from ``util.halo_start``, the tile's first halo row
+clipped to the map; tiles overlap by K_h - stride rows), and
+``util.dw_halo_tile`` builds the zero border in a VMEM halo tile and reads
+stride-2 columns from it with a strided read.  So the conv padding, the
+stride phases and the last tile's extra rows never exist in HBM, and VMEM
+use is bounded whatever the map size.
 """
 from __future__ import annotations
 
@@ -22,32 +25,34 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.util import (apply_act, halo_block, lane_tile,
+from repro.kernels.util import (LANES, apply_act, dw_halo_tile, halo_block,
+                                halo_start, halo_tile_width, lane_tile,
                                 pad_axis, resolve_interpret, row_tiling,
-                                split_w_phases, vmem_row_bytes, window_tap)
+                                vmem_row_bytes)
 
 
-def _dw_kernel(x_ref, w_ref, *rest, kh: int, kw: int, stride: int, wh: int,
-               fuse_bias: bool, act: str | None):
-    """x_ref: (1, span, Wp, bc) padded halo rows, columns stride-phase
-    split (phase width ``wh``); w_ref: (kh, kw, bc);
-    o_ref: (1, bh, Wo, bc).  The bias operand only exists when
-    ``fuse_bias`` — no zeros block is streamed for bias-less convs."""
+def _dw_kernel(x_ref, w_ref, *rest, h: int, pad: int, kh: int, kw: int,
+               stride: int, fuse_bias: bool, act: str | None):
+    """x_ref: (1, rows, W, bc) halo rows of the unpadded map; w_ref:
+    (kh, kw, bc); o_ref: (1, bh, Wo, bc); xs_ref: the VMEM halo tile,
+    one lane tile of channels at a time.  The bias operand only exists
+    when ``fuse_bias`` — no zeros block is streamed for bias-less convs."""
     if fuse_bias:
-        b_ref, o_ref = rest
+        b_ref, o_ref, xs_ref = rest
     else:
-        (o_ref,), b_ref = rest, None
+        (o_ref, xs_ref), b_ref = rest, None
     _, bh, wo, bc = o_ref.shape
-    acc = jnp.zeros((bh, wo, bc), jnp.float32)
-    for i in range(kh):          # unrolled window taps — every tap reads the
-        for j in range(kw):      # same VMEM tile (line-buffer reuse)
-            tap = window_tap(x_ref, (0,), i, j, bh, wo, stride, wh)
-            acc = acc + tap.astype(jnp.float32) * w_ref[i, j, :].astype(
-                jnp.float32)
-    if fuse_bias:
-        acc = acc + b_ref[...].astype(jnp.float32)
-    o_ref[0] = apply_act(acc, act).astype(o_ref.dtype)
+    for c0 in range(0, bc, LANES):
+        cw = min(LANES, bc - c0)
+        acc = dw_halo_tile(x_ref, xs_ref, w_ref, c0, cw,
+                           tile=pl.program_id(2), h=h, pad=pad, kh=kh, kw=kw,
+                           stride=stride, bh=bh, wo=wo)
+        if fuse_bias:
+            acc = acc + b_ref[0, pl.ds(c0, cw)].astype(jnp.float32)
+        o_ref[0, :, :, pl.ds(c0, cw)] = apply_act(acc, act).astype(
+            o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("stride", "pad", "act",
@@ -67,40 +72,44 @@ def depthwise_conv2d(x: jax.Array, w: jax.Array,
     bc = lane_tile(block_c, c)
     ho = (h + 2 * pad - kh) // stride + 1
     wo = (wd + 2 * pad - kw) // stride + 1
-    wp_ = wd + 2 * pad
-    row = (2 * stride * vmem_row_bytes(wp_, bc)      # halo, double-buffered
+    tile = (halo_tile_width(wd, pad), min(bc, LANES))  # VMEM halo tile row
+    row = (2 * stride * vmem_row_bytes(wd, bc)       # halo, double-buffered
+           + stride * vmem_row_bytes(*tile)          # halo tile
            + 3 * vmem_row_bytes(wo, bc))             # acc + output x2
-    bh, n_ht, span, extra_h = row_tiling(ho, stride, kh, h + 2 * pad, row)
-    # pad channels to a block multiple, spatial by the conv padding (plus
-    # the rows the last tile's halo reads)
-    xp, wh = split_w_phases(
-        pad_axis(jnp.pad(x, ((0, 0), (pad, pad + extra_h), (pad, pad),
-                             (0, 0))), 3, bc), stride)
+    bh, n_ht, span, _ = row_tiling(ho, stride, kh, h + 2 * pad, row)
+    rows = min(span, h)
+    # channels padded to a block multiple only where the block does not
+    # divide them; the spatial border is built in VMEM
+    xp = pad_axis(x, 3, bc)
     wp = pad_axis(w, 2, bc)
     fuse_bias = bias is not None
-    cp = xp.shape[3]
-    n_c = cp // bc
+    n_c = xp.shape[3] // bc
     grid = (n, n_c, n_ht)
     in_specs = [
         # one channel tile: a literal 0 offset, which the compiler can
         # prove lane-aligned even when bc < 128
-        pl.BlockSpec(halo_block(span, xp.shape[2], bc),
-                     lambda i, j, t: (i, t * bh * stride, 0,
+        pl.BlockSpec(halo_block(rows, wd, bc),
+                     lambda i, j, t: (i, halo_start(t, bh, stride, pad, h,
+                                                    rows), 0,
                                       j * bc if n_c > 1 else 0)),
         pl.BlockSpec((kh, kw, bc), lambda i, j, t: (0, 0, j)),
     ]
     operands = [xp, wp]
     if fuse_bias:
-        in_specs.append(pl.BlockSpec((bc,), lambda i, j, t: (j,)))
-        operands.append(pad_axis(bias, 0, bc))
-    out = pl.pallas_call(
-        functools.partial(_dw_kernel, kh=kh, kw=kw, stride=stride, wh=wh,
-                          fuse_bias=fuse_bias, act=act),
+        # a (1, C) row: a 1-D block narrower than the array is refused
+        # by the TPU compiler (its XLA layout tiles the whole vector)
+        in_specs.append(pl.BlockSpec((1, bc), lambda i, j, t: (0, j)))
+        operands.append(pad_axis(bias, 0, bc).reshape(1, -1))
+    return pl.pallas_call(
+        functools.partial(_dw_kernel, h=h, pad=pad, kh=kh, kw=kw,
+                          stride=stride, fuse_bias=fuse_bias, act=act),
         grid=grid,
         in_specs=in_specs,
+        # the last row tile (and channel tile) may overhang: Pallas drops
+        # the rows past ``ho``, so no slice of the output follows
         out_specs=pl.BlockSpec((1, bh, wo, bc),
                                lambda i, j, t: (i, t, 0, j)),
-        out_shape=jax.ShapeDtypeStruct((n, n_ht * bh, wo, cp), x.dtype),
+        out_shape=jax.ShapeDtypeStruct((n, ho, wo, c), x.dtype),
+        scratch_shapes=[pltpu.VMEM((span, *tile), jnp.float32)],
         interpret=interpret,
     )(*operands)
-    return out[:, :ho, :, :c]

@@ -22,9 +22,17 @@ then feeds that scratch to an MXU GEMM against its pointwise-weight
 columns (c-core analogue).  The C_out grid dimension is innermost, so the
 scratch survives across tiles and the dw pass runs once per row tile.
 HBM sees the block input once (plus the halo rows) and the block output
-once.  Stride-2 taps read contiguous rows and phase-split columns
-(``util.window_tap``): the TPU compiler refuses strided slices of loaded
-values and strided ref reads wider than one lane tile.
+once.
+
+``fused_dw_pw_conv`` takes the map unpadded: ``util.dw_halo_tile`` builds
+the zero border in a VMEM halo tile and reads stride-2 columns from it
+with a strided read, one lane tile at a time, so neither the conv padding
+nor a stride-phase split exists in HBM, and the overhanging last row and
+C_out tiles are dropped by Pallas instead of sliced off.  The inverted
+residual still zero-pads its narrow pre-expand input in the wrapper and
+reads stride-2 taps from phase-split columns (``util.window_tap``): the
+TPU compiler refuses strided slices of loaded values and strided ref
+reads wider than one lane tile.
 """
 from __future__ import annotations
 
@@ -35,10 +43,12 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.util import (apply_act as _act, cdiv, halo_block,
-                                lane_tile, mxu_dot, pad_axis, pad_to,
-                                resolve_interpret, row_tiling,
-                                split_w_phases, vmem_row_bytes, window_tap)
+from repro.kernels.util import (LANES, apply_act as _act, cdiv,
+                                dw_halo_tile, halo_block, halo_start,
+                                halo_tile_width, lane_tile, mxu_dot,
+                                pad_axis, pad_to, resolve_interpret,
+                                row_tiling, split_w_phases, vmem_row_bytes,
+                                window_tap)
 
 
 def _dw_tile(ref, lead: tuple, x_c0, w_ref, w_c0, bc, kh, kw, stride, bh,
@@ -58,12 +68,13 @@ def _dw_tile(ref, lead: tuple, x_c0, w_ref, w_c0, bc, kh, kw, stride, bh,
     return acc
 
 
-def _fused_dw_pw_kernel(x_ref, dw_w_ref, *rest, kh, kw, stride, wh, bc, nc,
+def _fused_dw_pw_kernel(x_ref, dw_w_ref, *rest, h, pad, kh, kw, stride,
                         has_dw_b, has_pw_b, has_res, dw_act, pw_act):
-    """Grid step (n, ht, co): x_ref (1,span,Wp,Cp) halo rows (columns
-    stride-phase split, phase width ``wh``); dw_w_ref
-    (kh,kw,Cp); optional dw_b (1,Cp) / pw_b (1,bn) / res (1,bh,wo,bn);
-    pw_w (Cp,bn); o_ref (1,bh,wo,bn); dws_ref (bh*wo, Cp) f32 scratch.
+    """Grid step (n, ht, co): x_ref (1,rows,W,Cp) halo rows of the unpadded
+    map (``util.halo_start``); dw_w_ref (kh,kw,Cp); optional dw_b (1,Cp) /
+    pw_b (1,bn) / res (1,bh,wo,bn); pw_w (Cp,bn); o_ref (1,bh,wo,bn);
+    dws_ref (bh*wo, Cp) f32 scratch; xs_ref the VMEM halo tile, one lane
+    tile of channels at a time (``util.dw_halo_tile``).
 
     The depthwise result of the row tile is computed channel-block-by-
     channel-block into the persistent VMEM scratch ONCE (co is the
@@ -76,18 +87,21 @@ def _fused_dw_pw_kernel(x_ref, dw_w_ref, *rest, kh, kw, stride, wh, bc, nc,
     pw_w_ref = rest.pop(0)
     pw_b_ref = rest.pop(0) if has_pw_b else None
     res_ref = rest.pop(0) if has_res else None
-    o_ref, dws_ref = rest
+    o_ref, dws_ref, xs_ref = rest
     _, bh, wo, bn = o_ref.shape
+    cp = dws_ref.shape[1]
+    tile = pl.program_id(1)
 
     @pl.when(pl.program_id(2) == 0)
     def _compute_dw():
-        for cblk in range(nc):       # p-core analogue, one channel block
-            c0 = cblk * bc           # of VMEM halo tile at a time
-            dw = _dw_tile(x_ref, (0,), c0, dw_w_ref, c0, bc, kh, kw,
-                          stride, bh, wo, wh)
+        for c0 in range(0, cp, LANES):   # p-core analogue, one lane tile
+            cw = min(LANES, cp - c0)     # of the halo at a time
+            dw = dw_halo_tile(x_ref, xs_ref, dw_w_ref, c0, cw,
+                              tile=tile, h=h, pad=pad, kh=kh,
+                              kw=kw, stride=stride, bh=bh, wo=wo)
             if dw_b_ref is not None:
-                dw = dw + dw_b_ref[0, c0:c0 + bc].astype(jnp.float32)
-            dws_ref[:, c0:c0 + bc] = _act(dw, dw_act).reshape(bh * wo, bc)
+                dw = dw + dw_b_ref[0, c0:c0 + cw].astype(jnp.float32)
+            dws_ref[:, c0:c0 + cw] = _act(dw, dw_act).reshape(bh * wo, cw)
 
     out = mxu_dot(dws_ref[...], pw_w_ref[...].astype(jnp.float32))
     if pw_b_ref is not None:
@@ -115,7 +129,8 @@ def fused_dw_pw_conv(x: jax.Array, dw_w: jax.Array,
     x: (N,H,W,C); dw_w: (Kh,Kw,C); pw_w: (C,Co); biases (C,)/(Co,) or None;
     residual: (N,Ho,Wo,Co) or None (added after pw_act).  ``block_c`` and
     ``block_n`` are lane-aligned by ``lane_tile``; the output-row tile is
-    sized to the VMEM budget.
+    sized to the VMEM budget.  The map goes to the kernel unpadded: the
+    halo tile and its zero border are built in VMEM.
     """
     interpret = resolve_interpret(interpret)
     n, h, wd, c = x.shape
@@ -124,24 +139,26 @@ def fused_dw_pw_conv(x: jax.Array, dw_w: jax.Array,
     co = pw_w.shape[1]
     ho = (h + 2 * pad - kh) // stride + 1
     wo = (wd + 2 * pad - kw) // stride + 1
-    wp_ = wd + 2 * pad
     bc = lane_tile(block_c, c)
     bn = lane_tile(block_n, co)
     cp = cdiv(c, bc) * bc
-    row = (2 * stride * vmem_row_bytes(wp_, cp)      # halo, double-buffered
+    tile = (halo_tile_width(wd, pad), min(cp, LANES))  # VMEM halo tile row
+    row = (2 * stride * vmem_row_bytes(wd, cp)       # halo, double-buffered
+           + stride * vmem_row_bytes(*tile)          # halo tile
            + vmem_row_bytes(wo, cp)                  # dw scratch
            + 4 * vmem_row_bytes(wo, bn))             # output (+res) x2
-    bh, n_ht, span, extra_h = row_tiling(ho, stride, kh, h + 2 * pad, row)
-    xp, wh = split_w_phases(
-        pad_axis(jnp.pad(x, ((0, 0), (pad, pad + extra_h), (pad, pad),
-                             (0, 0))), 3, bc), stride)
+    bh, n_ht, span, _ = row_tiling(ho, stride, kh, h + 2 * pad, row)
+    rows = min(span, h)
+    # channels padded only where the block does not divide them: the
+    # padded lanes must be zeros, since they meet zero pw rows in the GEMM
+    xp = pad_axis(x, 3, bc)
     dw_wp = pad_axis(dw_w, 2, bc)
     pw_wp = pad_to(pad_axis(pw_w, 0, bc), (cp, bn))
-    cop = pw_wp.shape[1]
-    grid = (n, n_ht, cop // bn)
+    grid = (n, n_ht, pw_wp.shape[1] // bn)
     in_specs = [
-        pl.BlockSpec(halo_block(span, xp.shape[2], cp),
-                     lambda i, t, j: (i, t * bh * stride, 0, 0)),
+        pl.BlockSpec(halo_block(rows, wd, cp),
+                     lambda i, t, j: (i, halo_start(t, bh, stride, pad, h,
+                                                    rows), 0, 0)),
         pl.BlockSpec((kh, kw, cp), lambda i, t, j: (0, 0, 0)),
     ]
     operands: list[jax.Array] = [xp, dw_wp]
@@ -157,21 +174,24 @@ def fused_dw_pw_conv(x: jax.Array, dw_w: jax.Array,
         assert residual.shape == (n, ho, wo, co), residual.shape
         in_specs.append(pl.BlockSpec((1, bh, wo, bn),
                                      lambda i, t, j: (i, t, 0, j)))
-        operands.append(pad_axis(pad_axis(residual, 3, bn), 1, bh))
-    out = pl.pallas_call(
-        functools.partial(_fused_dw_pw_kernel, kh=kh, kw=kw, stride=stride,
-                          wh=wh, bc=bc, nc=cp // bc, has_dw_b=dw_b is not None,
+        operands.append(residual)
+    return pl.pallas_call(
+        functools.partial(_fused_dw_pw_kernel, h=h, pad=pad, kh=kh, kw=kw,
+                          stride=stride, has_dw_b=dw_b is not None,
                           has_pw_b=pw_b is not None,
                           has_res=residual is not None, dw_act=dw_act,
                           pw_act=pw_act),
         grid=grid,
         in_specs=in_specs,
+        # the last row tile and C_out tile may overhang the output (and
+        # the residual): Pallas drops what lies past (ho, co), so neither
+        # is padded or sliced in HBM
         out_specs=pl.BlockSpec((1, bh, wo, bn), lambda i, t, j: (i, t, 0, j)),
-        out_shape=jax.ShapeDtypeStruct((n, n_ht * bh, wo, cop), x.dtype),
-        scratch_shapes=[pltpu.VMEM((bh * wo, cp), jnp.float32)],
+        out_shape=jax.ShapeDtypeStruct((n, ho, wo, co), x.dtype),
+        scratch_shapes=[pltpu.VMEM((bh * wo, cp), jnp.float32),
+                        pltpu.VMEM((span, *tile), jnp.float32)],
         interpret=interpret,
     )(*operands)
-    return out[:, :ho, :, :co]
 
 
 def _fused_pw_dw_pw_kernel(x_ref, exp_w_ref, *rest, kh, kw, stride, pad, h,

@@ -89,11 +89,70 @@ def row_tiling(out_rows: int, stride: int, kh: int, in_rows: int,
 
 
 def halo_block(span: int, w: int, c: int):
-    """Element-indexed block of ``span`` rows x the full padded width: the
-    index map returns the first row of each output-row tile's halo, so
+    """Element-indexed block of ``span`` rows x the full width: the index
+    map returns the first row of each output-row tile's halo, so
     consecutive tiles overlap by the window's extra rows and one grid step
     holds only its own rows in VMEM."""
     return (pl.Element(1), pl.Element(span), pl.Element(w), pl.Element(c))
+
+
+def halo_start(tile, bh: int, stride: int, pad: int, h: int, rows: int):
+    """First map row of the ``rows``-row block (``rows <= h``) that holds
+    every in-map row of output-row tile ``tile``'s halo, whose first row
+    is map row ``tile*stride*bh - pad`` (negative in the top border): that
+    row, clipped so the block stays inside the ``h``-row map.  The index
+    map and the kernel both call it, so they agree on the block."""
+    return jnp.clip(tile * (stride * bh) - pad, 0, h - rows)
+
+
+def halo_tile_width(w: int, pad: int) -> int:
+    """Columns of the VMEM halo tile of :func:`dw_halo_tile` for a
+    ``w``-column map: the map starts at the first sublane-aligned column
+    at or after ``pad`` (so each row is copied in with aligned stores),
+    with ``pad`` zero columns on either side of it."""
+    return cdiv(pad, SUBLANES) * SUBLANES + w + pad
+
+
+def dw_halo_tile(x_ref, xs_ref, w_ref, c0: int, cw: int, *, tile, h: int,
+                 pad: int, kh: int, kw: int, stride: int, bh: int,
+                 wo: int) -> jax.Array:
+    """Depthwise conv of channels ``[c0, c0 + cw)`` of one output-row tile,
+    from a halo block of the *unpadded* NHWC map -> f32 (bh, wo, cw).
+
+    ``x_ref`` (1, rows, W, C) holds map rows ``[halo_start(...), ... +
+    rows)``.  ``xs_ref`` (span, :func:`halo_tile_width`, >= cw) f32 is the
+    VMEM halo tile: map rows ``[tile*stride*bh - pad, ... + span)``, the
+    ``pad`` columns either side of the map and every row outside the map
+    zero, so the conv's zero padding never exists in HBM.  Taps read
+    ``xs_ref`` with the conv's stride on both axes (a strided ref read, at
+    most one lane tile wide: ``cw <= 128``).  Same taps, same order and
+    f32 arithmetic as the reference depthwise conv; ``w_ref`` (kh, kw, C)
+    shares ``x_ref``'s channel index."""
+    span, wp, _ = xs_ref.shape
+    _, rows, w, _ = x_ref.shape
+    lo = wp - w - pad                  # the map's first column in the tile
+    row0 = tile * (stride * bh) - pad  # map row of the tile's first row
+    start = halo_start(tile, bh, stride, pad, h, rows)
+    if pad:
+        zeros = jnp.zeros((span, pad, cw), jnp.float32)
+        xs_ref[:, lo - pad:lo, :cw] = zeros
+        xs_ref[:, lo + w:, :cw] = zeros
+
+    def copy_row(k, carry):
+        r = row0 + k
+        v = x_ref[0, jnp.clip(r - start, 0, rows - 1), :, pl.ds(c0, cw)]
+        xs_ref[k, lo:lo + w, :cw] = jnp.where(
+            (r >= 0) & (r < h), v.astype(jnp.float32), 0.0)
+        return carry
+
+    jax.lax.fori_loop(0, span, copy_row, 0)
+    acc = jnp.zeros((bh, wo, cw), jnp.float32)
+    for i in range(kh):          # unrolled window taps — every tap reads the
+        for j in range(kw):      # same VMEM tile (line-buffer reuse)
+            tap = xs_ref[pl.ds(i, bh, stride=stride),
+                         pl.ds(lo - pad + j, wo, stride=stride), :cw]
+            acc = acc + tap * w_ref[i, j, pl.ds(c0, cw)].astype(jnp.float32)
+    return acc
 
 
 def split_w_phases(xp: jax.Array, stride: int) -> tuple[jax.Array, int]:
@@ -101,8 +160,12 @@ def split_w_phases(xp: jax.Array, stride: int) -> tuple[jax.Array, int]:
     ``k*stride + p`` moves to ``p*wh + k``.  Every window tap then reads
     ``wo`` *contiguous* columns.  The TPU compiler refuses a strided slice
     of a loaded value, and a strided ref read wider than one lane tile, so
-    stride-2 taps are never strided along W in-kernel.  Returns the
-    rearranged map and the phase width ``wh``."""
+    these taps are never strided along W in-kernel.  Only the kernels that
+    zero-pad in the wrapper still use it (the inverted residual, which
+    pads its narrow pre-expand input, and the implicit GEMM); the
+    depthwise kernels read stride-2 columns from the VMEM halo tile
+    (:func:`dw_halo_tile`).  Returns the rearranged map and the phase
+    width ``wh``."""
     if stride == 1:
         return xp, xp.shape[2]
     n, h, w, c = xp.shape

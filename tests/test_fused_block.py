@@ -1,5 +1,6 @@
 """Fused dw->pw block kernels, implicit-GEMM conv across the model zoo,
 the graph fusion pass, and the block-shape autotuner cache."""
+import functools
 import json
 
 import jax
@@ -10,6 +11,8 @@ import pytest
 from repro.core.fusion import fused_layer_counts, plan_fusion
 from repro.core.graph import LayerSpec
 from repro.kernels import autotune
+from repro.kernels import util as kernel_util
+from repro.kernels.fused_block import kernel as fused_kernel
 from repro.kernels.conv_gemm.ops import conv2d_gemm, implicit_gemm_conv
 from repro.kernels.conv_gemm.ref import conv2d_ref
 from repro.kernels.fused_block.kernel import (fused_dw_pw_conv,
@@ -29,24 +32,48 @@ def rand(key, shape, scale=1.0, dtype=jnp.float32):
 # --------------------------------------------------------------------------
 # fused dw->pw vs the composed reference ops
 # --------------------------------------------------------------------------
-@pytest.mark.parametrize("h,w,c,co,s,bc,act", [
-    (14, 14, 64, 128, 1, 32, "relu6"),
-    (15, 13, 48, 56, 1, 32, "relu6"),     # odd H/W
-    (28, 28, 100, 64, 2, 48, "relu6"),    # stride 2, C % block_c != 0
-    (9, 9, 24, 40, 2, 64, "relu"),        # odd + stride 2 + bc > C
-    (7, 7, 96, 32, 1, 8, None),
+def _case(h, w, c, co, s, bc, act, limit=0, id=None):
+    return pytest.param(h, w, c, co, s, bc, act, limit,
+                        id=id or f"{h}-{w}-{c}-{co}-{s}-{bc}-{act}")
+
+
+@pytest.mark.parametrize("h,w,c,co,s,bc,act,limit", [
+    _case(14, 14, 64, 128, 1, 32, "relu6"),
+    _case(15, 13, 48, 56, 1, 32, "relu6"),     # odd H/W
+    _case(28, 28, 100, 64, 2, 48, "relu6"),    # stride 2, C % block_c != 0
+    _case(9, 9, 24, 40, 2, 64, "relu"),        # odd + stride 2 + bc > C
+    _case(7, 7, 96, 32, 1, 8, None),
+    # the zero border built in VMEM: odd H and W at stride 2 over several
+    # row tiles (the top one in the border, a bottom one overhanging)
+    _case(13, 11, 48, 56, 2, 48, "relu6", 2, id="odd-s2-tiles"),
+    # C > 128: two lane tiles of one channel block, then two channel
+    # blocks; C_out over two tiles, the last one overhanging
+    _case(15, 13, 200, 160, 1, 256, "relu6", 3, id="c200-s1-tiles"),
+    _case(11, 9, 200, 160, 2, 128, None, 2, id="c200-two-blocks-s2-tiles"),
 ])
-def test_fused_dw_pw_matches_composed(h, w, c, co, s, bc, act):
-    x = rand(KEYS[0], (2, h, w, c), 0.5)
+def test_fused_dw_pw_matches_composed(h, w, c, co, s, bc, act, limit,
+                                      monkeypatch):
+    """Against the composed reference on maps of non-zero mean with a
+    non-zero dw bias and relu6, so a border not read as zeros shows."""
+    x = rand(KEYS[0], (2, h, w, c), 0.5) + 0.5
     dw_w = rand(KEYS[1], (3, 3, c), 0.3)
-    dw_b = rand(KEYS[2], (c,), 0.1)
+    dw_b = rand(KEYS[2], (c,), 0.1) + 0.5
     pw_w = rand(KEYS[3], (c, co), 0.2)
     pw_b = rand(KEYS[4], (co,), 0.1)
-    out = fused_dw_pw_conv(x, dw_w, dw_b, pw_w, pw_b, stride=s, pad=1,
-                           dw_act="relu6", pw_act=act, block_c=bc,
-                           block_n=64)
+    if limit:                       # ``limit`` output rows per row tile
+        monkeypatch.setattr(fused_kernel, "row_tiling", functools.partial(
+            kernel_util.row_tiling, limit=limit))
+        jax.clear_caches()
+    try:
+        out = fused_dw_pw_conv(x, dw_w, dw_b, pw_w, pw_b, stride=s, pad=1,
+                               dw_act="relu6", pw_act=act, block_c=bc,
+                               block_n=64)
+    finally:
+        if limit:
+            jax.clear_caches()
     ref = fused_dw_pw_ref(x, dw_w, dw_b, pw_w, pw_b, stride=s, pad=1,
                           dw_act="relu6", pw_act=act)
+    assert out.shape == ref.shape
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=1e-4, atol=1e-4)
 

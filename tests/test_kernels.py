@@ -1,6 +1,8 @@
 """Per-kernel validation: shape/dtype sweeps + hypothesis property tests,
 all against the pure-jnp oracles, in Pallas interpret mode (CPU)."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -11,6 +13,8 @@ from repro.kernels.conv_gemm.kernel import matmul_bias_act
 from repro.kernels.conv_gemm.ops import (conv2d_gemm, implicit_gemm_conv,
                                          pointwise_conv, xla_routed)
 from repro.kernels.conv_gemm.ref import conv2d_ref, matmul_bias_act_ref
+from repro.kernels import util as kernel_util
+from repro.kernels.depthwise import kernel as depthwise_kernel
 from repro.kernels.depthwise.ops import depthwise
 from repro.kernels.depthwise.ref import depthwise_conv2d_ref
 from repro.kernels.attention.kernel import flash_attention
@@ -198,14 +202,36 @@ def test_stem_route_is_one_xla_conv(dtype, with_bias):
 # depthwise (p-core analogue)
 # --------------------------------------------------------------------------
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-@pytest.mark.parametrize("h,c,s", [(14, 512, 1), (28, 256, 2), (7, 1024, 1),
-                                   (9, 24, 2), (112, 32, 1)])
-def test_depthwise_shapes(h, c, s, dtype):
-    x = rand(KEYS[0], (2, h, h, c), dtype, 0.5)
-    w = rand(KEYS[1], (3, 3, c), dtype, 0.3)
+@pytest.mark.parametrize("h,w,c,s,limit,block_c", [
+    pytest.param(h, h, c, s, 0, None, id=f"{h}-{c}-{s}")
+    for h, c, s in [(14, 512, 1), (28, 256, 2), (7, 1024, 1), (9, 24, 2),
+                    (112, 32, 1)]] + [
+    # the zero border built in VMEM: odd H and W, several row tiles (the
+    # top one in the border, interior ones, a bottom one overhanging)
+    pytest.param(13, 11, 24, 2, 2, None, id="odd-s2-tiles"),
+    pytest.param(15, 15, 48, 1, 3, None, id="s1-tiles"),
+    # C > 128: two lane tiles of one channel block, and two channel blocks
+    pytest.param(11, 9, 200, 2, 2, None, id="c200-s2-tiles"),
+    pytest.param(11, 9, 200, 2, 2, 128, id="c200-two-blocks-s2-tiles"),
+])
+def test_depthwise_shapes(h, w, c, s, limit, block_c, dtype, monkeypatch):
+    """Against the reference on maps of non-zero mean with a non-zero bias
+    and relu6, so a border not read as zeros shows."""
+    x = rand(KEYS[0], (2, h, w, c), dtype, 0.5) + 0.5
+    k = rand(KEYS[1], (3, 3, c), dtype, 0.3)
     b = rand(KEYS[2], (c,), dtype)
-    out = depthwise(x, w, b, stride=s, pad=1, act="relu6")
-    ref = depthwise_conv2d_ref(x, w, b, stride=s, pad=1, act="relu6")
+    if limit:                       # ``limit`` output rows per row tile
+        monkeypatch.setattr(depthwise_kernel, "row_tiling", functools.partial(
+            kernel_util.row_tiling, limit=limit))
+        jax.clear_caches()
+    try:
+        out = depthwise(x, k, b, stride=s, pad=1, act="relu6",
+                        block_c=block_c)
+    finally:
+        if limit:
+            jax.clear_caches()
+    ref = depthwise_conv2d_ref(x, k, b, stride=s, pad=1, act="relu6")
+    assert out.shape == ref.shape
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(ref, np.float32), **tol(dtype))
 

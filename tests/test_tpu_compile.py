@@ -107,6 +107,56 @@ def test_depthwise_compiles(one_chip, shape, stride):
              one_chip, shape, (3, 3, c), (c,))
 
 
+# an instruction of the entry computation: name, shape, opcode
+_ENTRY_OP = re.compile(
+    r"^\s*(?:ROOT )?%([\w.-]+) = \w+\[([\d,]*)\]\{[^}]*\} ([\w-]+)\(", re.M)
+_GLUE = ("copy", "pad", "slice", "transpose")
+
+
+@pytest.mark.parametrize("kind,shape,stride,co", [
+    ("fused_dw_pw", (32, 112, 112, 32), 1, 16),     # mobilenet_v2 b1
+    ("depthwise", (32, 112, 112, 96), 2, None),     # b2_dw
+    ("depthwise", (32, 56, 56, 144), 1, None),      # b3_dw
+    ("fused_dw_pw", (32, 56, 56, 144), 2, 32),      # b4 dw + project
+    ("fused_dw_pw", (32, 14, 14, 576), 2, 160),     # b14 dw + project
+    ("depthwise", (32, 112, 112, 64), 2, None),     # mobilenet_v1 dw2
+])
+def test_depthwise_halo_built_in_vmem(one_chip, kind, shape, stride, co):
+    """At batch 32 the depthwise kernels take the map as it is: the
+    program holds the kernel, no ``pad``, ``slice`` or ``transpose`` of an
+    activation's size (conv border, stride phases, overhanging rows), and
+    no activation-sized ``copy`` but one layout conversion per map at the
+    program's boundary."""
+    n, h, w, c = shape
+    ho, wo = (h - 1) // stride + 1, (w - 1) // stride + 1
+    out = (n, ho, wo, c if co is None else co)
+    if kind == "depthwise":
+        text = _hlo(lambda x, k, b: depthwise(
+            x, k, b, stride=stride, pad=1, act="relu6", interpret=False),
+            one_chip, shape, (3, 3, c), (c,))
+        kernel = "depthwise_conv2d"
+    else:
+        text = _hlo(lambda x, dw, db, pw, pb: fused_dw_pw(
+            x, dw, db, pw, pb, stride=stride, pad=1, interpret=False),
+            one_chip, shape, (3, 3, c), (c,), (c, co), (co,))
+        kernel = "fused_dw_pw_conv"
+    act = min(n * h * w * c, n * ho * wo * out[-1])
+    glue = []
+    for name, dims, op in _ENTRY_OP.findall(text[text.index("\nENTRY"):]):
+        dims = tuple(int(d) for d in dims.split(",") if d)
+        size = 1
+        for d in dims:
+            size *= d
+        kinds = {op, name.split(".")[0]} & set(_GLUE)
+        if kinds and size >= act:
+            glue.append((kinds.pop(), dims))
+    assert re.search(rf"%{kernel}[.\d]* = .*custom-call\(", text)
+    assert all(op == "copy" for op, _ in glue), glue
+    copies = [dims for _, dims in glue]
+    assert set(copies) <= {shape, out} and len(set(copies)) == len(copies), \
+        glue
+
+
 def test_fused_inverted_residual_stride2_compiles(one_chip):
     """mobilenet_v2 b2: pw-expand 16->96, dw s2, pw-project 96->24."""
     _compile(lambda x, ew, eb, dw, db, pw, pb: fused_inverted_residual(

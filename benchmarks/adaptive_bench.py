@@ -100,7 +100,7 @@ def bench_adaptive(report: dict, image_size: int, requests: int,
     from repro.serving import Request, ShedPolicy
 
     eng0, pool = build_cnn_fleet(list(MODELS), weights=MIX_A,
-                                 use_pallas=True, fuse="group")
+                                 use_pallas=True)
     runners = {m.name: m.engine.runner for m in eng0.members}
 
     def fresh_fleet():

@@ -92,7 +92,7 @@ def bench_chaos(report: dict, image_size: int, requests: int,
 
     def build():
         eng, pool = build_cnn_fleet(list(MIX), weights=MIX,
-                                    use_pallas=True, fuse="group")
+                                    use_pallas=True)
         return {m.name: m.engine.runner for m in eng.members}, pool
 
     def fresh_fleet(runners, pool):

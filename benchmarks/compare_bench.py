@@ -2,7 +2,7 @@
 the committed baseline and fail on large per-entry slowdowns.
 
 Gated metrics are the wall-clock fields this repo's perf story is built on
-(``implicit_ms`` / ``fused_ms`` from ``BENCH_kernels.json``,
+(``fused_ms`` from ``BENCH_kernels.json``,
 ``pipelined_ms`` from ``BENCH_dualcore.json``, ``p50_ms`` / ``p95_ms``
 request latencies from ``BENCH_serving.json`` / ``BENCH_fleet.json``),
 plus two higher-is-better fields: ``aggregate_fps`` from
@@ -10,7 +10,7 @@ plus two higher-is-better fields: ``aggregate_fps`` from
 ``goodput_fps`` from ``BENCH_chaos.json`` (in-SLO throughput under
 injected faults), which fail when fresh drops below baseline /
 threshold.  Baseline-leg timings
-(im2col, unfused, sequential) and the remaining throughput fields (fps,
+(unfused, sequential) and the remaining throughput fields (fps,
 tokens/s) are deliberately *not* gated — a slower baseline is not a
 regression.  Entries present on only one side are
 reported but never fail the gate (shapes come and go as benches evolve).
@@ -30,8 +30,7 @@ import dataclasses
 import json
 import sys
 
-GATED_FIELDS = ("implicit_ms", "fused_ms", "pipelined_ms",
-                "p50_ms", "p95_ms")
+GATED_FIELDS = ("fused_ms", "pipelined_ms", "p50_ms", "p95_ms")
 GATED_HIGHER_FIELDS = ("aggregate_fps",        # regression = fresh DROPS
                        "goodput_fps")
 

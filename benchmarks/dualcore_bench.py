@@ -49,7 +49,7 @@ def bench_model_side(report: dict) -> None:
           f"{'sim T_b2':>12}{'sim ms':>8}{'fps':>8}{'speedup':>9}")
     for model in MODELS:
         graph = get_graph(model)
-        program = build_program(graph, use_pallas=True, fuse=False)
+        program = build_program(graph, use_pallas=True)
         report["model_side"][model] = {}
         for scheme in SCHEMES:
             sched = build_schedule(graph, DUAL_BASELINE, board, scheme)
@@ -94,8 +94,7 @@ def bench_measured(report: dict, image_size: int, reps: int) -> None:
     for model in MODELS:
         params, _, graph = build_model(model)
         sched = build_schedule(graph, DUAL_BASELINE, board, "balanced")
-        runner = DualCoreRunner(model, params, sched, use_pallas=True,
-                                fuse="group")
+        runner = DualCoreRunner(model, params, sched, use_pallas=True)
         es = runner.plan.exec_schedule
         sim = simulate_dual_core(es)
         imgs = [jax.random.normal(k, (1, image_size, image_size, 3))

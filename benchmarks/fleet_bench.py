@@ -72,7 +72,7 @@ def bench_fleet(report: dict, image_size: int, requests: int,
     from repro.serving import Request, poisson_arrivals, replay
 
     engine, pool = build_cnn_fleet(list(MIX), weights=MIX,
-                                   use_pallas=True, fuse="group")
+                                   use_pallas=True)
     runners = {m.name: m.engine.runner for m in engine.members}
     tags = mix_schedule(MIX, requests)
     keys = jax.random.split(jax.random.PRNGKey(0), requests)

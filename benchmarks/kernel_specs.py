@@ -7,10 +7,9 @@ kernel's blocking: inputs read once per tile-pass, outputs written once.
 v5e ridge point = 197e12 / 819e9 ~= 240 flops/byte.
 
 ``--smoke`` additionally measures real wall-clock on this container
-(interpret mode) for the two perf claims this repo tracks from PR 2 on —
-im2col-materializing vs implicit-GEMM conv, and the fused dw->pw block vs
-the unfused two-kernel path — and writes ``BENCH_kernels.json`` so CI keeps
-a perf trajectory (DESIGN.md §6).
+(interpret mode) for the fused blocks (dw->pw and pw->dw->pw) against the
+unfused per-layer kernels, and writes ``BENCH_kernels.json`` so CI keeps a
+perf trajectory (DESIGN.md §6).
 """
 from __future__ import annotations
 
@@ -87,15 +86,12 @@ def _time_ms(fn, reps: int = 3) -> float:
 
 
 def smoke(out_path: str = "BENCH_kernels.json", reps: int = 4) -> dict:
-    """Measure im2col-vs-implicit and fused-vs-unfused wall-clock on small
-    model-zoo shapes, write the JSON perf artifact, return it."""
+    """Measure fused-vs-unfused wall-clock on small model-zoo shapes,
+    write the JSON perf artifact, return it."""
     import jax
     import jax.numpy as jnp
 
     from repro.kernels import autotune
-    from repro.kernels.conv_gemm.kernel import (conv2d_implicit_gemm,
-                                                matmul_bias_act)
-    from repro.kernels.conv_gemm.ref import im2col
     from repro.kernels.conv_gemm.ops import pointwise_conv
     from repro.kernels.depthwise.ops import depthwise
     from repro.kernels.fused_block.ops import (fused_dw_pw,
@@ -104,40 +100,10 @@ def smoke(out_path: str = "BENCH_kernels.json", reps: int = 4) -> dict:
     keys = jax.random.split(jax.random.PRNGKey(0), 8)
     report: dict = {"backend": jax.default_backend(),
                     "interpret": jax.default_backend() == "cpu",
-                    "reps": reps, "conv_implicit_gemm": [],
-                    "fused_dw_pw": [], "fused_pw_dw_pw": [],
+                    "reps": reps, "fused_dw_pw": [], "fused_pw_dw_pw": [],
                     "autotune": []}
 
     print("\n## kernel smoke bench (wall-clock on this container)")
-    # -- implicit GEMM vs HBM-materialized im2col (zoo conv shapes) --------
-    for h, ci, co, k, s, p in [(56, 16, 64, 3, 1, 1),    # sqz fire e3x3
-                               (28, 32, 128, 3, 1, 1)]:  # fire4-ish
-        x = (jax.random.normal(keys[0], (1, h, h, ci)) * 0.5)
-        w = (jax.random.normal(keys[1], (k, k, ci, co)) * 0.2)
-        ho = (h + 2 * p - k) // s + 1
-        patch_bytes = ho * ho * k * k * ci * 4        # the HBM blow-up
-        ifm_bytes = h * h * ci * 4                    # implicit traffic
-
-        def run_im2col():
-            pm, (n, ho_, wo_) = im2col(x, k, k, s, p)
-            return matmul_bias_act(pm, w.reshape(k * k * ci, co)
-                                   ).reshape(n, ho_, wo_, co)
-
-        t_im2col = _time_ms(run_im2col, reps)
-        t_impl = _time_ms(lambda: conv2d_implicit_gemm(x, w, stride=s,
-                                                       pad=p), reps)
-        row = {"shape": f"{h}x{h}x{ci}->{co} k{k} s{s}",
-               "im2col_ms": round(t_im2col, 2),
-               "implicit_ms": round(t_impl, 2),
-               "speedup": round(t_im2col / t_impl, 2),
-               "im2col_hbm_patch_bytes": patch_bytes,
-               "implicit_ifm_bytes": ifm_bytes,
-               "hbm_traffic_ratio": round(patch_bytes / ifm_bytes, 2)}
-        report["conv_implicit_gemm"].append(row)
-        print(f"conv {row['shape']:<24} im2col {t_im2col:8.1f}ms  "
-              f"implicit {t_impl:8.1f}ms  ({row['speedup']}x, "
-              f"{row['hbm_traffic_ratio']}x less HBM)")
-
     # -- fused dw->pw vs unfused two-kernel path (MobileNet-v1 blocks) -----
     for h, c, co, s in [(14, 256, 256, 1),   # mbv1 dw7..11/pw
                         (14, 512, 512, 1),
@@ -197,7 +163,7 @@ def smoke(out_path: str = "BENCH_kernels.json", reps: int = 4) -> dict:
               f"fused {t_fus:8.1f}ms  ({row['speedup']}x)")
 
     # -- autotuner: tune one signature per kind, report the winners --------
-    for sig in [autotune.LayerSig("conv", 14, 14, 32, 64, 3, 3, 1, 1),
+    for sig in [autotune.LayerSig("pointwise", 14, 14, 256, 256),
                 autotune.LayerSig("fused_dw_pw", 14, 14, 128, 128, 3, 3,
                                   1, 1)]:
         cfg = autotune.tune_layer(sig, reps=1)

@@ -63,7 +63,7 @@ def bench_multipool(report: dict, image_size: int, requests: int,
     # plus the single-pool reference set
     def build():
         eng, pool = build_cnn_fleet(list(MIX), weights=MIX,
-                                    use_pallas=True, fuse="group")
+                                    use_pallas=True)
         return {m.name: m.engine.runner for m in eng.members}, pool
 
     single_runners, single_pool = build()

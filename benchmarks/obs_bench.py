@@ -63,7 +63,7 @@ def bench_obs(report: dict, image_size: int, requests: int,
 
     def build():
         eng, pool = build_cnn_fleet(list(MIX), weights=MIX,
-                                    use_pallas=True, fuse="group")
+                                    use_pallas=True)
         return {m.name: m.engine.runner for m in eng.members}, pool
 
     pool_sets = [build() for _ in range(POOLS)]

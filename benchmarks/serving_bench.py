@@ -64,8 +64,7 @@ def bench_cnn(report: dict, image_size: int, requests: int,
     for model in CNN_MODELS:
         params, _, graph = build_model(model)
         sched = build_schedule(graph, DUAL_BASELINE, board, "balanced")
-        runner = DualCoreRunner(model, params, sched, use_pallas=True,
-                                fuse="group")
+        runner = DualCoreRunner(model, params, sched, use_pallas=True)
         imgs = [jax.random.normal(k, (1, image_size, image_size, 3))
                 for k in jax.random.split(jax.random.PRNGKey(0), requests)]
         runner.run_sequential(imgs[:1])        # warm the per-group jits

@@ -52,6 +52,26 @@ def _linear_next(graph: LayerGraph, name: str) -> str | None:
     return succ[0]
 
 
+def match_chain(graph: LayerGraph,
+                chain: list[str]) -> FusionGroup | None:
+    """The fused group that the consecutive layers ``chain`` start with —
+    pw->dw->pw over the first three, else dw->pw over the first two —
+    or None.  The one fusability rule: both :func:`plan_fusion` and the
+    dual-core runtime's within-group fusion ask it."""
+    sub = [graph.layer(n) for n in chain[:3]]
+
+    def linear(i: int) -> bool:
+        return _linear_next(graph, chain[i]) == chain[i + 1]
+
+    if (len(sub) == 3 and _is_pw(sub[0]) and sub[1].op == "dwconv"
+            and _is_pw(sub[2]) and linear(0) and linear(1)):
+        return FusionGroup("pw_dw_pw", tuple(chain[:3]))
+    if (len(sub) >= 2 and sub[0].op == "dwconv" and _is_pw(sub[1])
+            and linear(0)):
+        return FusionGroup("dw_pw", tuple(chain[:2]))
+    return None
+
+
 def plan_fusion(graph: LayerGraph) -> list[FusionGroup]:
     """Greedy fusion plan over the graph in topological order."""
     order = graph.topological_order()
@@ -60,26 +80,14 @@ def plan_fusion(graph: LayerGraph) -> list[FusionGroup]:
     for l in order:
         if l.name in consumed:
             continue
-        group = _match(graph, l)
+        chain = [l.name]
+        while len(chain) < 3 and (nxt := _linear_next(graph, chain[-1])):
+            chain.append(nxt)
+        group = (match_chain(graph, chain)
+                 or FusionGroup("single", (l.name,)))
         plan.append(group)
         consumed.update(group.layers)
     return plan
-
-
-def _match(graph: LayerGraph, l: LayerSpec) -> FusionGroup:
-    # pw-expand -> dw -> pw-project (matched first: see module docstring)
-    if _is_pw(l):
-        dn = _linear_next(graph, l.name)
-        if dn is not None and graph.layer(dn).op == "dwconv":
-            pn = _linear_next(graph, dn)
-            if pn is not None and _is_pw(graph.layer(pn)):
-                return FusionGroup("pw_dw_pw", (l.name, dn, pn))
-    # dw -> pw
-    if l.op == "dwconv":
-        pn = _linear_next(graph, l.name)
-        if pn is not None and _is_pw(graph.layer(pn)):
-            return FusionGroup("dw_pw", (l.name, pn))
-    return FusionGroup("single", (l.name,))
 
 
 def fused_layer_counts(graph: LayerGraph) -> dict[str, int]:

@@ -2,23 +2,23 @@
 
 The dual-core runtime needs to execute *parts* of a network on different
 submeshes, so the hand-written per-model forward functions are factored into
-a flat list of :class:`Step` objects — the program.  Each step covers one or
-more graph layers (a fused MobileNet block is one step), reads/writes named
-buffers in an environment dict, and knows how to run itself given the
-parameter pytree.  ``repro.models.cnn`` runs the whole program in order (the
-sequential forward — numerically identical to the pre-refactor code);
-``repro.dualcore.runtime`` partitions the same program into alternating
-c-/p-core groups from a :class:`~repro.core.scheduler.Schedule` and pipelines
-images through them.  Because both paths execute the *same* step objects, the
-pipelined outputs are bitwise-equal to the sequential forward by
-construction (a test asserts it).
+a flat list of per-layer :class:`Step` objects — the program.  Each step
+reads/writes named buffers in an environment dict and knows how to run
+itself given the parameter pytree.  ``repro.dualcore.runtime`` partitions the
+program into alternating c-/p-core exec groups from a
+:class:`~repro.core.scheduler.Schedule` and pipelines images through them;
+on the Pallas path :func:`regroup_fused` then fuses dw->pw and
+pw->dw->pw chains *within* each group into single fused-block steps.  The
+sequential forward (``repro.models.cnn``, :meth:`Program.run`) is the same
+program as one group, fused the same way.  On the XLA path nothing fuses,
+so the runner's groups run exactly the sequential forward's steps.
 
 Buffer conventions: the main chain flows through ``"h"``; the final logits
 land in ``"out"``; SqueezeNet fire modules use ``"sq"``/``"e1"`` for the
 squeeze/expand branches; the MobileNet-v2 per-layer path stashes the block
 input in ``"res"`` for the residual add.  ``collect`` dicts receive
-activation *shapes* (never values), recorded at trace time, with exactly the
-same keys as the pre-refactor forwards.
+activation *shapes* (never values), recorded at trace time, keyed by the
+layer (a fused step records its last layer).
 """
 from __future__ import annotations
 
@@ -29,8 +29,7 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 
-from repro.core.fusion import (FusionGroup, _is_pw, _linear_next,
-                               plan_fusion)
+from repro.core.fusion import match_chain
 from repro.core.graph import LayerGraph, LayerSpec
 from repro.kernels.conv_gemm.ops import conv2d_gemm
 from repro.kernels.conv_gemm.ref import conv2d_ref
@@ -128,17 +127,29 @@ class Program:
     act_of: Callable[[str], str | None]
     use_pallas: bool
 
+    @functools.cached_property
+    def sequential_steps(self) -> list[Step]:
+        """The whole program as one exec group, fused as the runner fuses
+        each of its groups (:func:`regroup_fused`)."""
+        return regroup_fused(self, [self.steps])[0]
+
     def run(self, params: Params, x: jax.Array,
             collect: dict | None = None) -> jax.Array:
         """Sequential execution — the plain forward pass."""
-        env: Env = {"h": x}
-        for s in self.steps:
-            s.fn(params, env, collect)
-        return env["out"]
+        return run_steps(self.sequential_steps, params, x, collect)
+
+
+def run_steps(steps: list[Step], params: Params, x: jax.Array,
+              collect: dict | None = None) -> jax.Array:
+    """Run ``steps`` in order on the input ``x``; returns ``out``."""
+    env: Env = {"h": x}
+    for s in steps:
+        s.fn(params, env, collect)
+    return env["out"]
 
 
 # --------------------------------------------------------------------------
-# step constructors (shared by the builders and the runtime's group fusion)
+# step constructors (shared by the builders and the within-group fusion)
 # --------------------------------------------------------------------------
 def layer_step(graph: LayerGraph, name: str,
                act_of: Callable[[str], str | None],
@@ -219,29 +230,7 @@ def head_step(graph: LayerGraph, name: str,
 # --------------------------------------------------------------------------
 # model builders
 # --------------------------------------------------------------------------
-def _fused_chain_steps(graph: LayerGraph,
-                       act_of: Callable[[str], str | None]) -> list[Step]:
-    """The Pallas fusion-plan path for the (almost) sequential nets: one
-    fused_block pallas_call per dw->pw / pw->dw->pw group, singles for the
-    rest (mirrors the pre-refactor ``_forward_fused_chain``)."""
-    steps: list[Step] = []
-    for grp in plan_fusion(graph):
-        first = graph.layer(grp.layers[0])
-        if grp.kind in ("dw_pw", "pw_dw_pw"):
-            steps.append(fused_step(graph, grp.kind, grp.layers, act_of))
-        elif first.op == "fc" and "avgpool" in first.fused:
-            steps.append(head_step(graph, first.name, act_of,
-                                   use_pallas=True, avgpool_first=True))
-        else:
-            steps.append(layer_step(graph, first.name, act_of,
-                                    use_pallas=True))
-    return steps
-
-
-def _mbv1_steps(graph: LayerGraph, use_pallas: bool,
-                fuse: bool) -> list[Step]:
-    if use_pallas and fuse:
-        return _fused_chain_steps(graph, mbv1_act)
+def _mbv1_steps(graph: LayerGraph, use_pallas: bool) -> list[Step]:
     steps = [layer_step(graph, l.name, mbv1_act, use_pallas)
              for l in graph.layers[:-1]]
     steps.append(head_step(graph, "fc", mbv1_act, use_pallas,
@@ -276,10 +265,7 @@ def _mbv2_layer_step(graph: LayerGraph, name: str,
                 fn=fn)
 
 
-def _mbv2_steps(graph: LayerGraph, use_pallas: bool,
-                fuse: bool) -> list[Step]:
-    if use_pallas and fuse:
-        return _fused_chain_steps(graph, mbv2_act)
+def _mbv2_steps(graph: LayerGraph, use_pallas: bool) -> list[Step]:
     steps = [_mbv2_layer_step(graph, l.name, use_pallas)
              for l in graph.layers[:-1]]
     steps.append(head_step(graph, "fc", mbv2_act, use_pallas,
@@ -320,10 +306,8 @@ def _sqz_fire_steps(graph: LayerGraph, fire: str, use_pallas: bool,
     ]
 
 
-def _sqz_steps(graph: LayerGraph, use_pallas: bool,
-               fuse: bool) -> list[Step]:
-    # no dwconv layers -> the fusion plan is all singletons; the per-layer
-    # kernels are already the fastest Pallas path (``fuse`` is a no-op)
+def _sqz_steps(graph: LayerGraph, use_pallas: bool) -> list[Step]:
+    # no dwconv layers: nothing fuses
     conv1 = graph.layer("conv1")
 
     def conv1_fn(params, env, collect):
@@ -358,34 +342,33 @@ _BUILDERS = {
 
 
 def build_program(name_or_graph: str | LayerGraph, *,
-                  use_pallas: bool = False, fuse: bool = True) -> Program:
-    """Build the step program for one zoo model.
+                  use_pallas: bool = False) -> Program:
+    """Build the per-layer step program for one zoo model.
 
-    ``use_pallas`` selects the kernel backend per layer; ``fuse`` (Pallas
-    path only) runs the fusion plan's dw->pw / pw->dw->pw groups as single
-    fused pallas_calls — exactly the pre-refactor forward semantics.
+    ``use_pallas`` selects the kernel backend per layer.  Fusion is not
+    the program's: :func:`regroup_fused` fuses within each exec group.
 
     Programs are pure (steps close over specs and read params per call),
-    so the by-name path is cached: repeated forward calls don't re-plan
-    fusion or re-allocate the step closures.
+    so the by-name path is cached: repeated forward calls don't re-fuse
+    or re-allocate the step closures.
     """
     if isinstance(name_or_graph, str):
-        return _cached_program(name_or_graph, use_pallas, fuse)
-    return _build(name_or_graph, use_pallas, fuse)
+        return _cached_program(name_or_graph, use_pallas)
+    return _build(name_or_graph, use_pallas)
 
 
 @functools.lru_cache(maxsize=None)
-def _cached_program(name: str, use_pallas: bool, fuse: bool) -> Program:
-    return _build(get_graph(name), use_pallas, fuse)
+def _cached_program(name: str, use_pallas: bool) -> Program:
+    return _build(get_graph(name), use_pallas)
 
 
-def _build(graph: LayerGraph, use_pallas: bool, fuse: bool) -> Program:
+def _build(graph: LayerGraph, use_pallas: bool) -> Program:
     try:
         builder = _BUILDERS[graph.name]
     except KeyError:
         raise KeyError(f"no step builder for graph {graph.name!r}; "
                        f"choices: {sorted(_BUILDERS)}") from None
-    steps = builder(graph, use_pallas, fuse)
+    steps = builder(graph, use_pallas)
     return Program(graph=graph, steps=steps, act_of=ACT_OF[graph.name],
                    use_pallas=use_pallas)
 
@@ -393,27 +376,34 @@ def _build(graph: LayerGraph, use_pallas: bool, fuse: bool) -> Program:
 def regroup_fused(program: Program,
                   groups: list[list[Step]]) -> list[list[Step]]:
     """Within-group fusion: given per-layer steps partitioned into core
-    groups, re-run the fusion matcher *inside* each group so dw->pw chains
-    that the schedule kept on one core run as single fused pallas_calls,
-    while chains the schedule split across cores stay per-layer.
+    groups, run the fusion matcher (``core.fusion.match_chain``) *inside*
+    each group so dw->pw chains that the schedule kept on one core run as
+    single fused pallas_calls, while chains the schedule split across
+    cores stay per-layer.  The fused-block kernels are Pallas-only: on
+    the XLA path the groups come back as they are.
 
     Only plain main-chain steps fuse (single-layer, reads==writes==("h",));
     branch/head/residual steps pass through untouched.
     """
+    if not program.use_pallas:
+        return groups
     graph, act_of = program.graph, program.act_of
     out: list[list[Step]] = []
     for grp in groups:
         fused: list[Step] = []
         i = 0
         while i < len(grp):
-            s = grp[i]
-            window = grp[i:i + 3]
-            m = _match_in(graph, window) if _plain(s) else None
+            chain = []
+            for s in grp[i:i + 3]:
+                if not _plain(s):
+                    break
+                chain.append(s.layers[0])
+            m = match_chain(graph, chain)
             if m is not None:
                 fused.append(fused_step(graph, m.kind, m.layers, act_of))
                 i += len(m.layers)
             else:
-                fused.append(s)
+                fused.append(grp[i])
                 i += 1
         out.append(fused)
     return out
@@ -422,29 +412,3 @@ def regroup_fused(program: Program,
 def _plain(s: Step) -> bool:
     return (len(s.layers) == 1 and s.reads == ("h",)
             and s.writes == ("h",))
-
-
-def _match_in(graph: LayerGraph,
-              window: list[Step]) -> FusionGroup | None:
-    """Fusion match constrained to consecutive plain steps of one group —
-    the same fusability rules as ``core.fusion`` (_is_pw/_linear_next),
-    with the extra constraint that the whole chain stays in the group
-    (``window`` never crosses a group boundary)."""
-    chain = []
-    for s in window:
-        if not _plain(s):
-            break
-        chain.append(s.layers[0])
-    sub = [graph.layer(n) for n in chain]
-
-    def linear(a, b):                # b is a's sole consumer and vice versa
-        return _linear_next(graph, a) == b
-
-    if (len(sub) >= 3 and _is_pw(sub[0]) and sub[1].op == "dwconv"
-            and _is_pw(sub[2]) and linear(chain[0], chain[1])
-            and linear(chain[1], chain[2])):
-        return FusionGroup("pw_dw_pw", tuple(chain[:3]))
-    if (len(sub) >= 2 and sub[0].op == "dwconv" and _is_pw(sub[1])
-            and linear(chain[0], chain[1])):
-        return FusionGroup("dw_pw", tuple(chain[:2]))
-    return None

@@ -4,10 +4,11 @@
 two-batch latency T_b2; this module is the missing execution half.  The
 device pool splits into a c-submesh and a p-submesh (``dualmesh.partition``,
 the Eq.10 theta split); each schedule group compiles to one jitted step
-placed on its core's submesh (c-groups dispatch the implicit-GEMM conv
-kernels, p-groups the depthwise / fused-block kernels); and N input images
-stream through the group chain with the paper's one-slot offset, so stream
-i runs group k while stream i+1 runs group k-1 on the other core.  JAX
+placed on its core's submesh (c-groups dispatch the 1x1 GEMM kernel and
+XLA's convolution, p-groups the depthwise / fused-block kernels); and N
+input images stream through the group chain with the paper's one-slot
+offset, so stream i runs group k while stream i+1 runs group k-1 on the
+other core.  JAX
 dispatch is asynchronous: both group calls of a slot are in flight together
 and the per-submesh execution queues realise the overlap.
 
@@ -15,7 +16,8 @@ Mapping a :class:`~repro.core.scheduler.Schedule` (layer-level) onto an
 executable step program (``dualcore.program``) happens in
 :func:`build_exec_plan`: each step is assigned the core where the schedule
 put the dominant share of its cycles, consecutive same-core steps merge into
-exec groups, and the merged chain is itself re-expressed as a ``Schedule``
+exec groups (on the Pallas path their dw->pw chains then fuse within the
+group), and the merged chain is itself re-expressed as a ``Schedule``
 (``plan.exec_schedule``) so T_b2 / the instruction-level simulator stay
 directly comparable with what actually runs.
 """
@@ -85,12 +87,11 @@ def _step_core(step: Step, lmap: dict[str, tuple[str, int]],
     return "c" if weight["c"] >= weight["p"] else "p"
 
 
-def build_exec_plan(program: Program, schedule: Schedule,
-                    group_fusion: bool = False) -> ExecPlan:
+def build_exec_plan(program: Program, schedule: Schedule) -> ExecPlan:
     """Partition ``program`` into alternating-core exec groups per the
-    schedule's allocation.  With ``group_fusion`` the per-layer steps of
-    each group are re-fused (dw->pw chains the schedule kept on one core
-    become single fused pallas_calls)."""
+    schedule's allocation.  On the Pallas path the per-layer steps of each
+    group are then fused (dw->pw chains the schedule kept on one core
+    become single fused pallas_calls, :func:`regroup_fused`)."""
     graph = program.graph
     lmap = _layer_core_map(schedule)
     missing = [n for s in program.steps for n in s.layers if n not in lmap]
@@ -108,8 +109,7 @@ def build_exec_plan(program: Program, schedule: Schedule,
         else:
             parts.append([step])
             part_cores.append(core)
-    if group_fusion:
-        parts = regroup_fused(program, parts)
+    parts = regroup_fused(program, parts)
     groups = [ExecGroup(core=c, steps=p)
               for c, p in zip(part_cores, parts)]
     exec_schedule = Schedule(
@@ -158,27 +158,28 @@ class DualCoreRunner:
     """Executes one CNN's schedule on the c/p submeshes, images pipelined
     with the one-slot offset of Fig.4b.
 
-    fuse='group' (default) builds the per-layer program and re-fuses dw->pw
-    chains *within* each exec group — fusion never crosses a core boundary,
-    so the schedule's allocation is honoured exactly.  fuse=True partitions
-    the full fusion-plan program (the sequential ``use_pallas=True`` path,
-    bitwise-identical steps); fuse=False keeps every layer its own kernel.
+    The per-layer program is partitioned into exec groups, and on the
+    Pallas path dw->pw chains are re-fused *within* each group — fusion
+    never crosses a core boundary, so the schedule's allocation is
+    honoured exactly.  ``use_pallas=False`` runs XLA's reference ops, with
+    every layer its own step.  Each group is one jitted program; on TPU
+    and GPU every group but the first donates its env.
+
+    ``fuse`` names that within-group fusion and has one legal value,
+    ``"group"``; any other raises ``ValueError``.  It stays only because
+    the benchmark's configurations (``chipbench/configs``) still pass it.
     """
 
     def __init__(self, graph: LayerGraph | str, params: Params,
                  schedule: Schedule, *, devices=None, theta: float = 0.5,
-                 use_pallas: bool = True, fuse: bool | str = "group",
-                 jit_groups: bool = True, donate: bool | None = None):
-        # the fused-block kernels are Pallas-only: on the XLA path both
-        # fusion modes degrade to per-layer steps
-        group_fusion = fuse == "group" and use_pallas
-        self.program = build_program(
-            graph, use_pallas=use_pallas,
-            fuse=bool(fuse) and not group_fusion)
+                 use_pallas: bool = True, fuse: str = "group"):
+        if fuse != "group":
+            raise ValueError(f"fuse={fuse!r}: exec groups fuse within a "
+                             f"core group only (fuse='group')")
+        self.program = build_program(graph, use_pallas=use_pallas)
         self.graph = self.program.graph
         self.schedule = schedule
-        self.plan = build_exec_plan(self.program, schedule,
-                                    group_fusion=group_fusion)
+        self.plan = build_exec_plan(self.program, schedule)
         self.groups = self.plan.groups
         # ``devices`` may be an already-split DualMesh — a fleet pool
         # leases one split to every member so they share the submeshes
@@ -194,10 +195,8 @@ class DualCoreRunner:
                  for n in g.layers},
                 self._shard[core])
             for core in ("c", "p")}
-        self.jit_groups = jit_groups
-        if donate is None:           # donation is a no-op on CPU backends
-            donate = jax.default_backend() in ("tpu", "gpu")
-        self._donate = donate
+        # donation is a no-op on CPU backends
+        self._donate = jax.default_backend() in ("tpu", "gpu")
         self._fns = self._compile_all()
 
     def _compile_all(self) -> list:
@@ -220,8 +219,6 @@ class DualCoreRunner:
         # program as ``jit_dualcore_g07_p``
         group_fn.__name__ = group_fn.__qualname__ = \
             f"dualcore_g{gi:02d}_{group.core}"
-        if not self.jit_groups:
-            return group_fn
         fn = group_fn
         mesh = self._shard[group.core].mesh
         if mesh.size > 1:

@@ -377,8 +377,7 @@ def build_cnn_fleet(models: Sequence[str], *,
                     scheme: str = "balanced",
                     plan=None,
                     use_pallas: bool = True,
-                    fuse: bool | str = "group",
-                    jit_groups: bool = True,
+                    fuse: str = "group",
                     policy: SchedulingPolicy | None = None,
                     weights: Mapping[str, float] | None = None,
                     admission: Mapping[str, AdmissionPolicy] | None = None,
@@ -398,10 +397,11 @@ def build_cnn_fleet(models: Sequence[str], *,
     (``"best"`` runs the full §V-A flow per model).  ``params`` maps
     every model to the weights it serves (``{layer: {"w", "b"}}``, as
     ``models.cnn.init_params`` lays them out); without it each model
-    gets ``build_model``'s seeded He weights.  ``record``, when given, is
-    handed to every member's ``DualCoreEngine`` as its ``record``: each
-    exec-group dispatch of any member appends ``(slot, rid, group,
-    core)`` to it (slot and rid count per member).
+    gets ``build_model``'s seeded He weights.  ``fuse`` goes to every
+    ``DualCoreRunner``, which accepts ``"group"`` alone.  ``record``,
+    when given, is handed to every member's ``DualCoreEngine`` as its
+    ``record``: each exec-group dispatch of any member appends ``(slot,
+    rid, group, core)`` to it (slot and rid count per member).
     """
     from repro.core.arch import BoardModel, DUAL_BASELINE
     from repro.core.scheduler import best_schedule, build_schedule
@@ -443,8 +443,7 @@ def build_cnn_fleet(models: Sequence[str], *,
                      else build_schedule(graph, cfg, board, scheme))
         runner = DualCoreRunner(model, model_params, sched,
                                 devices=pool.lease(model),
-                                use_pallas=use_pallas, fuse=fuse,
-                                jit_groups=jit_groups)
+                                use_pallas=use_pallas, fuse=fuse)
         members[model] = DualCoreEngine(runner, max_queue=max_queue,
                                         record=record)
     engine = FleetEngine(members, policy=policy, weights=weights,

@@ -8,9 +8,8 @@ Each kernel ships three files (repo convention):
   ref.py     pure-jnp oracle used by the allclose test sweeps
 
 Kernels (see DESIGN.md for the dual-OPU mapping):
-  conv_gemm    c-core analogue — implicit-GEMM conv (patch tiles gathered
-               in VMEM, no HBM im2col matrix) + tiled GEMM 1x1/fc fast
-               path, fused bias+ReLU6 epilogue
+  conv_gemm    c-core analogue — tiled GEMM for 1x1 convs and fc, fused
+               bias+ReLU6 epilogue; every other conv on XLA's convolution
   depthwise    p-core analogue — VMEM halo tile (the line-buffer port)
   fused_block  dw->pw and pw-expand->dw->pw-project in ONE pallas_call;
                the intermediate feature maps never leave VMEM

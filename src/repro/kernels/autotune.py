@@ -13,8 +13,8 @@ Cache format (``autotune_cache.json``)::
     {
       "version": 1,
       "entries": {
-        "conv/h14.w14.ci32.co64.k3x3.s1.p1/f32": {
-          "config": {"block_h": 9, "block_n": 64},
+        "depthwise/h14.w14.ci576.co576.k3x3.s1.p1/f32": {
+          "config": {"block_c": 128},
           "us": 1234.5,
           "backend": "cpu"
         },
@@ -37,6 +37,7 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import functools
 import json
 import os
 from typing import Any, Callable
@@ -59,7 +60,7 @@ _RECORDING: list["LayerSig"] | None = None
 class LayerSig:
     """Kernel-shape signature — the autotune cache key (DESIGN.md §4)."""
 
-    kind: str                    # 'conv' | 'pointwise' | 'depthwise' |
+    kind: str                    # 'pointwise' | 'depthwise' |
                                  # 'fused_dw_pw' | 'fused_pw_dw_pw'
     H: int
     W: int
@@ -146,13 +147,8 @@ def heuristic_config(sig: LayerSig) -> dict:
     kernels size their output-row tiles to the VMEM budget themselves, so
     the full channel count is the default wherever channels are blocked."""
     from repro.kernels.util import lane_tile
-    if sig.kind == "conv":
-        wo = max(1, (sig.W + 2 * sig.pad - sig.K_w) // sig.stride + 1)
-        ho = max(1, (sig.H + 2 * sig.pad - sig.K_h) // sig.stride + 1)
-        return {"block_h": max(1, min(ho, -(-256 // wo))),
-                "block_n": lane_tile(128, sig.C_o)}
     if sig.kind == "pointwise":
-        return {"block": (128, 128, 128)}
+        return {"block": [128, 128, 128]}
     if sig.kind == "depthwise":
         return {"block_c": sig.C_i}
     if sig.kind in ("fused_dw_pw", "fused_pw_dw_pw"):
@@ -166,14 +162,8 @@ def candidates(sig: LayerSig) -> list[dict]:
     compiler accepts: a failing candidate is an error, not a skip."""
     from repro.kernels.util import lane_tile
     out: list[dict] = [heuristic_config(sig)]
-    if sig.kind == "conv":
-        ho = max(1, (sig.H + 2 * sig.pad - sig.K_h) // sig.stride + 1)
-        for bh in (1, 4, 8, 16):
-            for bn in (128, 256):
-                out.append({"block_h": min(bh, ho),
-                            "block_n": lane_tile(bn, sig.C_o)})
-    elif sig.kind == "pointwise":
-        for b in ((128, 128, 128), (256, 128, 128), (256, 256, 256)):
+    if sig.kind == "pointwise":
+        for b in ([128, 128, 128], [256, 128, 128], [256, 256, 256]):
             out.append({"block": b})
     elif sig.kind == "depthwise":
         for bc in (128, 256):
@@ -242,15 +232,7 @@ def tune_layer(sig: LayerSig, *, path: str | None = None, reps: int = 3,
     x = (jax.random.normal(kx, (1, sig.H, sig.W, sig.C_i)) * 0.3
          ).astype(dtype)
 
-    if sig.kind == "conv":
-        from repro.kernels.conv_gemm.kernel import conv2d_implicit_gemm
-        w = (jax.random.normal(kw, (sig.K_h, sig.K_w, sig.C_i, sig.C_o))
-             * 0.2).astype(dtype)
-
-        def run(cfg):
-            return lambda: conv2d_implicit_gemm(
-                x, w, stride=sig.stride, pad=sig.pad, **cfg)
-    elif sig.kind == "pointwise":
+    if sig.kind == "pointwise":
         from repro.kernels.conv_gemm.kernel import matmul_bias_act
         xm = x.reshape(sig.H * sig.W, sig.C_i)
         w = (jax.random.normal(kw, (sig.C_i, sig.C_o)) * 0.2).astype(dtype)
@@ -319,14 +301,19 @@ def record_signatures():
 
 def zoo_signatures(image_size: int = 224,
                    models: tuple[str, ...] = ZOO_MODELS) -> list[LayerSig]:
-    """Every layer signature the zoo forwards consult at ``image_size`` —
-    per-layer and fused-block paths both — discovered by abstractly
-    evaluating the real step programs with signature recording on, so the
-    sweep can never drift from what the op wrappers actually ask for."""
+    """Every layer signature the zoo's served programs consult at
+    ``image_size``: each model's exec groups as the dual-core runner
+    builds them (balanced schedule, dw->pw chains fused within a group),
+    run in chain order under abstract evaluation with signature
+    recording on, so the sweep can never drift from what the op wrappers
+    actually ask for."""
     import jax
     import jax.numpy as jnp
 
-    from repro.dualcore.program import build_program
+    from repro.core.arch import DUAL_BASELINE, BoardModel
+    from repro.core.scheduler import build_schedule
+    from repro.dualcore.program import build_program, run_steps
+    from repro.dualcore.runtime import build_exec_plan
     from repro.models.cnn import init_params
     from repro.models.zoo import get_graph
 
@@ -334,16 +321,18 @@ def zoo_signatures(image_size: int = 224,
     seen: set[str] = set()
     x = jnp.zeros((1, image_size, image_size, 3), jnp.float32)
     for name in models:
-        params = init_params(get_graph(name), jax.random.PRNGKey(0))
-        for fuse in (False, True):
-            prog = build_program(name, use_pallas=True, fuse=fuse)
-            with record_signatures() as rec:
-                jax.eval_shape(
-                    lambda p, xx, prog=prog: prog.run(p, xx), params, x)
-            for s in rec:
-                if s.key() not in seen:
-                    seen.add(s.key())
-                    sigs.append(s)
+        graph = get_graph(name)
+        params = init_params(graph, jax.random.PRNGKey(0))
+        plan = build_exec_plan(
+            build_program(name, use_pallas=True),
+            build_schedule(graph, DUAL_BASELINE, BoardModel(), "balanced"))
+        steps = [s for g in plan.groups for s in g.steps]
+        with record_signatures() as rec:
+            jax.eval_shape(functools.partial(run_steps, steps), params, x)
+        for s in rec:
+            if s.key() not in seen:
+                seen.add(s.key())
+                sigs.append(s)
     return sigs
 
 

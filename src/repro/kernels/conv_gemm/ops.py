@@ -6,18 +6,15 @@ the autotune cache when a tuned entry exists for the layer signature,
 else the default.
 
 Every other conv (the RGB stems, squeezenet's 3x3 fire expands) runs on
-XLA's convolution, not on the implicit-GEMM kernel (``kernel.py``,
-DESIGN.md §1).  The kernel's per-tap dot contracts over ``C_i`` alone and
-its operands carry ``C_i`` padded to 128 lanes through every halo DMA and
-the wrapper's pad and phase-split copies.  On one TPU v5e (device time
-from a profiler trace, ``benchmarks/conv_route_bench.py``) XLA's
-convolution was faster on every shape measured: the 3-channel stem 1.75x
-at batch 1 and 6.3x at batch 32, the four distinct fire expands (16-64
-channels) 1.18-1.28x and 1.98-2.60x, two lane-full 3x3 convs (128 and
-256 channels) 1.06-1.12x and 1.38-1.48x, two strided 1x1 convs (64 and
-128 channels) 1.08-1.09x and 5.4-6.6x.  :func:`implicit_gemm_conv` keeps
-the kernel callable with its tuned or heuristic blocks, for that
-benchmark and the tests.
+XLA's convolution (DESIGN.md §1).  An implicit-GEMM Pallas kernel for
+these convs contracted each tap over ``C_i`` alone and carried ``C_i``
+padded to 128 lanes through every halo DMA and the wrapper's pad and
+phase-split copies.  On one TPU v5e (device time from a profiler trace)
+XLA's convolution was faster on every shape measured: the 3-channel stem
+1.75x at batch 1 and 6.3x at batch 32, the four distinct fire expands
+(16-64 channels) 1.18-1.28x and 1.98-2.60x, two lane-full 3x3 convs (128
+and 256 channels) 1.06-1.12x and 1.38-1.48x, two strided 1x1 convs (64
+and 128 channels) 1.08-1.09x and 5.4-6.6x.  So that kernel is gone.
 """
 from __future__ import annotations
 
@@ -25,17 +22,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels import autotune
-from repro.kernels.conv_gemm.kernel import (DEFAULT_BLOCK,
-                                            conv2d_implicit_gemm,
-                                            matmul_bias_act)
+from repro.kernels.conv_gemm.kernel import DEFAULT_BLOCK, matmul_bias_act
 from repro.kernels.util import apply_act
-
-
-def _sig(kind: str, x: jax.Array, kh: int, kw: int, ci: int, co: int,
-         stride: int, pad: int) -> autotune.LayerSig:
-    return autotune.LayerSig(kind=kind, H=x.shape[1], W=x.shape[2],
-                             C_i=ci, C_o=co, K_h=kh, K_w=kw, stride=stride,
-                             pad=pad, dtype=str(x.dtype))
 
 
 def conv2d_gemm(x: jax.Array, w: jax.Array, bias: jax.Array | None = None,
@@ -45,7 +33,7 @@ def conv2d_gemm(x: jax.Array, w: jax.Array, bias: jax.Array | None = None,
 
     x: (N, H, W, C_i); w: (K_h, K_w, C_i, C_o); bias: (C_o,) or None.
     A plain 1x1 conv runs the tiled GEMM; every other conv
-    (:func:`xla_routed`) runs XLA's convolution, which beat the
+    (:func:`xla_routed`) runs XLA's convolution, which beat an
     implicit-GEMM kernel on every shape measured (module docstring).
     """
     kh, kw, ci, co = w.shape
@@ -59,24 +47,6 @@ def xla_routed(kh: int, kw: int, stride: int, pad: int) -> bool:
     """Whether :func:`conv2d_gemm` runs this conv on XLA's convolution:
     every conv but a plain 1x1 (stride 1, no padding)."""
     return not (kh == 1 and kw == 1 and stride == 1 and pad == 0)
-
-
-def implicit_gemm_conv(x: jax.Array, w: jax.Array,
-                       bias: jax.Array | None = None, *, stride: int = 1,
-                       pad: int = 0, act: str | None = None, block=None,
-                       interpret: bool | None = None) -> jax.Array:
-    """The implicit-GEMM kernel with the layer's autotuned blocks (the
-    per-kind heuristic where none is cached) unless ``block`` is given.
-    No route of :func:`conv2d_gemm` runs it."""
-    kh, kw, ci, co = w.shape
-    if block is not None:
-        bh, bn = block
-    else:
-        sig = _sig("conv", x, kh, kw, ci, co, stride, pad)
-        cfg = autotune.get_config(sig) or autotune.heuristic_config(sig)
-        bh, bn = cfg["block_h"], cfg["block_n"]
-    return conv2d_implicit_gemm(x, w, bias, stride=stride, pad=pad, act=act,
-                                block_h=bh, block_n=bn, interpret=interpret)
 
 
 def xla_conv(x: jax.Array, w: jax.Array, bias: jax.Array | None = None, *,
@@ -109,7 +79,8 @@ def pointwise_conv(x: jax.Array, w: jax.Array,
         w = w.reshape(w.shape[2], w.shape[3])
     co = w.shape[-1]
     if block is None:
-        sig = _sig("pointwise", x, 1, 1, ci, co, 1, 0)
+        sig = autotune.LayerSig(kind="pointwise", H=h, W=wd, C_i=ci, C_o=co,
+                                dtype=str(x.dtype))
         cfg = autotune.get_config(sig)
         block = tuple(cfg["block"]) if cfg else DEFAULT_BLOCK
     out = matmul_bias_act(x.reshape(n * h * wd, ci), w, bias, block=block,

@@ -1,8 +1,4 @@
-"""Pure-jnp oracles for the conv_gemm kernels.
-
-``im2col`` lives here ONLY as the test oracle (and the baseline leg of the
---smoke benchmark): the execution path never materializes a patch matrix —
-see conv2d_implicit_gemm (DESIGN.md §1)."""
+"""Pure-jnp oracles for the conv_gemm path (DESIGN.md §1)."""
 from __future__ import annotations
 
 import jax
@@ -20,24 +16,6 @@ def matmul_bias_act_ref(x: jax.Array, w: jax.Array,
     elif act == "relu6":
         out = jnp.clip(out, 0.0, 6.0)
     return out.astype(x.dtype)
-
-
-def im2col(x: jax.Array, kh: int, kw: int, stride: int,
-           pad: int) -> jax.Array:
-    """NHWC -> (N*Ho*Wo, kh*kw*C) patch matrix."""
-    n, h, w, c = x.shape
-    xp = jnp.pad(x, ((0, 0), (pad, pad), (pad, pad), (0, 0)))
-    ho = (h + 2 * pad - kh) // stride + 1
-    wo = (w + 2 * pad - kw) // stride + 1
-    idx_h = jnp.arange(ho) * stride
-    idx_w = jnp.arange(wo) * stride
-    patches = []
-    for i in range(kh):
-        for j in range(kw):
-            patches.append(xp[:, idx_h + i][:, :, idx_w + j])  # (n,ho,wo,c)
-    # (n, ho, wo, kh*kw, c) -> (n*ho*wo, kh*kw*c)
-    pm = jnp.stack(patches, axis=3)
-    return pm.reshape(n * ho * wo, kh * kw * c), (n, ho, wo)
 
 
 def conv2d_ref(x: jax.Array, w: jax.Array, bias: jax.Array | None = None,

@@ -160,12 +160,11 @@ def split_w_phases(xp: jax.Array, stride: int) -> tuple[jax.Array, int]:
     ``k*stride + p`` moves to ``p*wh + k``.  Every window tap then reads
     ``wo`` *contiguous* columns.  The TPU compiler refuses a strided slice
     of a loaded value, and a strided ref read wider than one lane tile, so
-    these taps are never strided along W in-kernel.  Only the kernels that
-    zero-pad in the wrapper still use it (the inverted residual, which
-    pads its narrow pre-expand input, and the implicit GEMM); the
-    depthwise kernels read stride-2 columns from the VMEM halo tile
-    (:func:`dw_halo_tile`).  Returns the rearranged map and the phase
-    width ``wh``."""
+    these taps are never strided along W in-kernel.  Only the kernel that
+    zero-pads in the wrapper still uses it (the inverted residual, which
+    pads its narrow pre-expand input); the depthwise kernels read
+    stride-2 columns from the VMEM halo tile (:func:`dw_halo_tile`).
+    Returns the rearranged map and the phase width ``wh``."""
     if stride == 1:
         return xp, xp.shape[2]
     n, h, w, c = xp.shape

@@ -9,16 +9,19 @@ Execution is expressed once, as a step program (``repro.dualcore.program``),
 and consumed two ways:
 
   * sequential forward (this module): run the steps in order on one device.
-    ``use_pallas`` selects XLA reference ops vs the Pallas kernels;
-    ``fuse=True`` (Pallas path) runs dw->pw / pw-expand->dw->pw-project
-    chains as single fused_block pallas_calls (DESIGN.md §3); ``fuse=False``
-    forces the per-layer kernels.
+    ``use_pallas`` selects XLA reference ops vs the Pallas kernels; on the
+    Pallas path the program runs as one exec group, its dw->pw /
+    pw-expand->dw->pw-project chains fused into single fused_block
+    pallas_calls by the same matcher the runtime applies per group
+    (DESIGN.md §3).
   * pipelined dual-core (``run_pipelined`` -> ``repro.dualcore.runtime``):
     the same steps partitioned into the alternating c/p-core groups of a
     scheduler ``Schedule`` and executed on the two submeshes with the
     paper's one-slot image offset (DESIGN.md §8).
 
-Because both paths execute the same step objects, they agree bitwise.
+On the XLA path both run the same step objects.  On the Pallas path a
+chain the schedule splits across cores fuses in the forward but not in the
+runtime, so the two agree to float rounding, not bitwise.
 """
 from __future__ import annotations
 
@@ -57,8 +60,8 @@ def init_params(graph: LayerGraph, key: jax.Array,
 
 def _make_forward(name: str) -> Callable:
     def forward(params: Params, x: jax.Array, use_pallas: bool = False,
-                collect: dict | None = None, fuse: bool = True) -> jax.Array:
-        prog = build_program(name, use_pallas=use_pallas, fuse=fuse)
+                collect: dict | None = None) -> jax.Array:
+        prog = build_program(name, use_pallas=use_pallas)
         return prog.run(params, x, collect)
 
     forward.__name__ = f"{name}_forward"
@@ -89,7 +92,6 @@ def build_model(name: str, key=None, dtype=jnp.float32):
 
 def run_pipelined(name: str, params: Params, schedule, images, *,
                   devices=None, use_pallas: bool = True,
-                  fuse: bool | str = "group", jit_groups: bool = True,
                   record: list | None = None):
     """Execute ``schedule`` for real: pipeline ``images`` through the
     alternating c/p-core group chain on the split device mesh with the
@@ -102,6 +104,5 @@ def run_pipelined(name: str, params: Params, schedule, images, *,
     from repro.dualcore.runtime import DualCoreRunner
 
     runner = DualCoreRunner(name, params, schedule, devices=devices,
-                            use_pallas=use_pallas, fuse=fuse,
-                            jit_groups=jit_groups)
+                            use_pallas=use_pallas)
     return runner.run_pipelined(images, record=record)

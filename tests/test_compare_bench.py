@@ -15,11 +15,9 @@ from benchmarks.compare_bench import compare, extract_metrics, main  # noqa: E40
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 REPORT = {
-    "conv_implicit_gemm": [
-        {"shape": "56x56x16->64 k3 s1", "im2col_ms": 120.0,
-         "implicit_ms": 10.0},
-        {"shape": "28x28x32->128 k3 s1", "im2col_ms": 80.0,
-         "implicit_ms": 8.0},
+    "fused_pw_dw_pw": [
+        {"shape": "14x14x64 t6 s1", "unfused_ms": 30.0, "fused_ms": 10.0},
+        {"shape": "7x7x160 t6 s1", "unfused_ms": 24.0, "fused_ms": 8.0},
     ],
     "fused_dw_pw": [
         {"shape": "14x14x256->256 s1", "unfused_ms": 30.0,
@@ -35,13 +33,13 @@ REPORT = {
 
 def test_extract_gates_only_our_legs():
     m = extract_metrics(REPORT)
-    # shape-labelled, stable keys; baseline legs (im2col/unfused/sequential
+    # shape-labelled, stable keys; baseline legs (unfused/sequential
     # timings, best_fps baseline throughput) are not gated
-    assert "conv_implicit_gemm/56x56x16->64 k3 s1/implicit_ms" in m
+    assert "fused_pw_dw_pw/14x14x64 t6 s1/fused_ms" in m
     assert "measured/mobilenet_v1/pipelined_ms" in m
     assert "fleet/aggregate_fps" in m
     assert len(m) == 5
-    assert not any("im2col" in k or "unfused" in k or "sequential" in k
+    assert not any("unfused" in k or "sequential" in k
                    or "best_fps" in k for k in m)
 
 
@@ -52,16 +50,16 @@ def test_identical_reports_pass():
 
 def test_gate_trips_on_injected_3x_regression():
     fresh = copy.deepcopy(REPORT)
-    fresh["conv_implicit_gemm"][0]["implicit_ms"] *= 3.0
+    fresh["fused_pw_dw_pw"][0]["fused_ms"] *= 3.0
     regs, _ = compare(REPORT, fresh, threshold=2.0)
     assert len(regs) == 1
-    assert regs[0].key == "conv_implicit_gemm/56x56x16->64 k3 s1/implicit_ms"
+    assert regs[0].key == "fused_pw_dw_pw/14x14x64 t6 s1/fused_ms"
     assert regs[0].ratio == pytest.approx(3.0)
 
 
 def test_gate_tolerates_sub_threshold_noise_and_new_entries():
     fresh = copy.deepcopy(REPORT)
-    fresh["conv_implicit_gemm"][0]["implicit_ms"] *= 1.9   # < 2x: noise
+    fresh["fused_pw_dw_pw"][0]["fused_ms"] *= 1.9         # < 2x: noise
     fresh["fused_dw_pw"].append(
         {"shape": "7x7x1024->1024 s1", "fused_ms": 99.0})  # new: not gated
     del fresh["measured"]["mobilenet_v1"]                  # gone: not gated

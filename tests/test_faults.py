@@ -449,10 +449,8 @@ def test_cnn_pool_crash_recovers_and_replays_bitwise(seed):
     REBALANCE re-leasing the survivor's split) and the faulted run
     replays bitwise — output arrays included."""
     def pools():
-        e0, _ = build_cnn_fleet(["squeezenet"], use_pallas=False,
-                                fuse=False)
-        e1, _ = build_cnn_fleet(["squeezenet"], use_pallas=False,
-                                fuse=False)
+        e0, _ = build_cnn_fleet(["squeezenet"], use_pallas=False)
+        e1, _ = build_cnn_fleet(["squeezenet"], use_pallas=False)
         return {"p0": e0, "p1": e1}
 
     def reqs():

@@ -391,7 +391,7 @@ def test_fleet_outputs_bitwise_equal_standalone_engines():
     from repro.serving import stream_images
 
     models = ["mobilenet_v1", "squeezenet"]
-    eng, pool = build_cnn_fleet(models, use_pallas=False, fuse=False)
+    eng, pool = build_cnn_fleet(models, use_pallas=False)
     assert set(pool.stats()["leases"]) == set(models)
     tags = mix_schedule({m: 0.5 for m in models}, 4)
     imgs = [jax.random.normal(k, (1, 32, 32, 3))
@@ -411,8 +411,7 @@ def test_fleet_outputs_bitwise_equal_standalone_engines():
 
         sched = build_schedule(graph, DUAL_BASELINE, BoardModel(),
                                "balanced")
-        runner = DualCoreRunner(m, params, sched, use_pallas=False,
-                                fuse=False)
+        runner = DualCoreRunner(m, params, sched, use_pallas=False)
         standalone[m] = iter(stream_images(runner, by_model[m]).outputs)
     for t, out in zip(tags, res.outputs):
         np.testing.assert_array_equal(np.asarray(out),
@@ -425,7 +424,7 @@ def test_real_engines_co_dispatch_on_shared_pool():
     """With real runners the interleaved fleet issues more member
     dispatches than fleet slots — cross-network groups share slots."""
     eng, _ = build_cnn_fleet(["mobilenet_v1", "squeezenet"],
-                             use_pallas=False, fuse=False)
+                             use_pallas=False)
     imgs = [jax.random.normal(k, (1, 32, 32, 3))
             for k in jax.random.split(jax.random.PRNGKey(1), 4)]
     tags = mix_schedule({"mobilenet_v1": 0.5, "squeezenet": 0.5}, 4)
@@ -457,7 +456,7 @@ def test_build_cnn_fleet_serves_the_given_params():
             for k in jax.random.split(jax.random.PRNGKey(2), 4)]
 
     def serve(**kw):
-        eng, _ = build_cnn_fleet(models, use_pallas=False, fuse=False, **kw)
+        eng, _ = build_cnn_fleet(models, use_pallas=False, **kw)
         for x, t in zip(imgs, tags):
             eng.submit(Request(x, model=t))
         return [np.asarray(y) for y in eng.drain().outputs]
@@ -466,8 +465,7 @@ def test_build_cnn_fleet_serves_the_given_params():
     for m in models:
         sched = build_schedule(get_graph(m), DUAL_BASELINE, BoardModel(),
                                "balanced")
-        runner = DualCoreRunner(m, params[m], sched, use_pallas=False,
-                                fuse=False)
+        runner = DualCoreRunner(m, params[m], sched, use_pallas=False)
         mine = [x for x, t in zip(imgs, tags) if t == m]
         want = stream_images(runner, mine).outputs
         got = [y for y, t in zip(given, tags) if t == m]
@@ -476,7 +474,7 @@ def test_build_cnn_fleet_serves_the_given_params():
             np.testing.assert_array_equal(g, np.asarray(w))
             assert not np.array_equal(g, o)
     with pytest.raises(ValueError, match="every member"):
-        build_cnn_fleet(models, use_pallas=False, fuse=False,
+        build_cnn_fleet(models, use_pallas=False,
                         params={"squeezenet": params["squeezenet"]})
 
 
@@ -500,7 +498,7 @@ def test_fleet_with_lm_member():
     params, _, graph = build_model("squeezenet")
     sched = build_schedule(graph, DUAL_BASELINE, BoardModel(), "balanced")
     cnn = DualCoreEngine(DualCoreRunner("squeezenet", params, sched,
-                                        use_pallas=False, fuse=False))
+                                        use_pallas=False))
     eng = FleetEngine({"lm": lm, "squeezenet": cnn})
     prompt = jax.random.randint(jax.random.PRNGKey(1), (1, 4), 0,
                                 cfg.vocab)
@@ -549,7 +547,7 @@ def test_build_cnn_fleet_realises_plan_theta():
     on a multi-device mesh the c/p chip ratio IS the planned config."""
     plan = plan_fleet({"squeezenet": 1.0}, config=DUAL_MULTI)
     eng, pool = build_cnn_fleet(["squeezenet"], plan=plan,
-                                use_pallas=False, fuse=False)
+                                use_pallas=False)
     assert pool.theta == plan.theta
     assert eng.members[0].engine.runner.schedule is \
         plan.schedules["squeezenet"]

@@ -376,7 +376,7 @@ _MODELS = ["mobilenet_v1", "squeezenet"]
 
 
 def _cnn_fleet():
-    return build_cnn_fleet(_MODELS, use_pallas=False, fuse=False)
+    return build_cnn_fleet(_MODELS, use_pallas=False)
 
 
 def _cnn_requests(n=4, seed=0):
@@ -445,7 +445,7 @@ def test_multipool_cnn_migration_rebalance_parity_vs_standalone():
     from repro.serving import stream_images
 
     e0, _ = _cnn_fleet()
-    e1, _ = build_cnn_fleet(["squeezenet"], use_pallas=False, fuse=False)
+    e1, _ = build_cnn_fleet(["squeezenet"], use_pallas=False)
     router = MultiPoolRouter({"p0": e0, "p1": e1})
     reqs = _cnn_requests(6, seed=3)
     for r in reqs:
@@ -470,8 +470,7 @@ def test_multipool_cnn_migration_rebalance_parity_vs_standalone():
         params, _, graph = build_model(m)
         sched = build_schedule(graph, DUAL_BASELINE, BoardModel(),
                                "balanced")
-        runner = DualCoreRunner(m, params, sched, use_pallas=False,
-                                fuse=False)
+        runner = DualCoreRunner(m, params, sched, use_pallas=False)
         standalone[m] = iter(stream_images(runner, by_model[m]).outputs)
     for r, out in zip(reqs, res.outputs):    # router submission order
         np.testing.assert_array_equal(
@@ -500,7 +499,7 @@ def test_multipool_lm_cnn_round_trip_bitwise():
         sched = build_schedule(graph, DUAL_BASELINE, BoardModel(),
                                "balanced")
         return DualCoreEngine(DualCoreRunner(
-            "squeezenet", params, sched, use_pallas=False, fuse=False))
+            "squeezenet", params, sched, use_pallas=False))
 
     def pools():
         lm = DualMeshEngine(DualMeshRunner(

@@ -1,5 +1,5 @@
-"""Fused dw->pw block kernels, implicit-GEMM conv across the model zoo,
-the graph fusion pass, and the block-shape autotuner cache."""
+"""Fused dw->pw block kernels, the conv path across the model zoo, the
+graph fusion pass, and the block-shape autotuner cache."""
 import functools
 import json
 
@@ -13,7 +13,7 @@ from repro.core.graph import LayerSpec
 from repro.kernels import autotune
 from repro.kernels import util as kernel_util
 from repro.kernels.fused_block import kernel as fused_kernel
-from repro.kernels.conv_gemm.ops import conv2d_gemm, implicit_gemm_conv
+from repro.kernels.conv_gemm.ops import conv2d_gemm
 from repro.kernels.conv_gemm.ref import conv2d_ref
 from repro.kernels.fused_block.kernel import (fused_dw_pw_conv,
                                               fused_pw_dw_pw_conv)
@@ -123,7 +123,7 @@ def test_fused_ops_accept_4d_pointwise_weights():
 
 
 # --------------------------------------------------------------------------
-# implicit-GEMM conv across every conv layer shape in the model zoo
+# the conv path across every conv layer shape in the model zoo
 # --------------------------------------------------------------------------
 def _zoo_conv_sigs():
     seen, sigs = set(), []
@@ -139,12 +139,11 @@ def _zoo_conv_sigs():
 
 
 @pytest.mark.parametrize("h,w,ci,co,kh,kw,s,p", _zoo_conv_sigs())
-def test_implicit_gemm_zoo_layer(h, w, ci, co, kh, kw, s, p):
+def test_conv2d_gemm_zoo_layer(h, w, ci, co, kh, kw, s, p):
     """Acceptance: ``conv2d_gemm`` matches conv2d_ref to 1e-4 on every
     conv layer in the model zoo, on the route it takes there: the tiled
     GEMM for 1x1 convs, XLA's convolution for every other conv
-    (``xla_routed``).  The implicit-GEMM kernel's own cases are
-    ``test_implicit_gemm_kernel_matches_ref``."""
+    (``xla_routed``)."""
     x = rand(KEYS[0], (1, h, w, ci), 0.5)
     wgt = rand(KEYS[1], (kh, kw, ci, co), 0.2)
     b = rand(KEYS[2], (co,), 0.1)
@@ -180,16 +179,10 @@ def _assert_no_patch_matrix(conv):
     walk(jaxpr.jaxpr)
 
 
-def test_implicit_gemm_never_materializes_patch_matrix():
+def test_conv_path_never_materializes_patch_matrix():
     """Acceptance: no (N*Ho*Wo, Kh*Kw*C) intermediate anywhere in the
     jaxpr of the conv path."""
     _assert_no_patch_matrix(conv2d_gemm)
-
-
-def test_implicit_gemm_kernel_never_materializes_patch_matrix():
-    """Nor in the implicit-GEMM kernel's, called directly (``conv2d_gemm``
-    runs XLA's convolution for a 3x3 conv)."""
-    _assert_no_patch_matrix(implicit_gemm_conv)
 
 
 # --------------------------------------------------------------------------
@@ -240,9 +233,9 @@ def test_fused_model_forward_matches_xla():
 # --------------------------------------------------------------------------
 def test_autotune_cache_roundtrip_deterministic(tmp_path):
     path = str(tmp_path / "autotune.json")
-    sig = autotune.LayerSig("conv", 8, 8, 8, 8, 3, 3, 1, 1)
+    sig = autotune.LayerSig("pointwise", 8, 8, 8, 8)
     cfg = autotune.tune_layer(sig, path=path, reps=1)
-    assert set(cfg) == {"block_h", "block_n"}
+    assert set(cfg) == {"block"}
     # the JSON file round-trips to the same config
     with open(path) as f:
         raw = json.load(f)
@@ -265,6 +258,6 @@ def test_autotune_miss_falls_back_to_heuristic(tmp_path):
 
 
 def test_autotune_key_distinguishes_shapes():
-    a = autotune.LayerSig("conv", 14, 14, 32, 64, 3, 3, 1, 1)
-    b = autotune.LayerSig("conv", 14, 14, 32, 64, 3, 3, 2, 1)
+    a = autotune.LayerSig("pointwise", 14, 14, 32, 64)
+    b = autotune.LayerSig("pointwise", 14, 14, 32, 128)
     assert a.key() != b.key()

@@ -10,8 +10,8 @@ import pytest
 from hypothesis import given, settings, strategies as st
 
 from repro.kernels.conv_gemm.kernel import matmul_bias_act
-from repro.kernels.conv_gemm.ops import (conv2d_gemm, implicit_gemm_conv,
-                                         pointwise_conv, xla_routed)
+from repro.kernels.conv_gemm.ops import (conv2d_gemm, pointwise_conv,
+                                         xla_routed)
 from repro.kernels.conv_gemm.ref import conv2d_ref, matmul_bias_act_ref
 from repro.kernels import util as kernel_util
 from repro.kernels.depthwise import kernel as depthwise_kernel
@@ -110,9 +110,8 @@ def _conv_route(x, w, b, stride, pad) -> str:
         assert "pallas_call" not in prims, prims
         return "xla"
     names = {e.params.get("name") for e in eqns}
-    for kernel in ("conv2d_implicit_gemm", "matmul_bias_act"):
-        if kernel in names:
-            return kernel
+    if "matmul_bias_act" in names:
+        return "matmul_bias_act"
     raise AssertionError(f"no conv route in {sorted(prims)}")
 
 
@@ -120,8 +119,8 @@ def _conv_route(x, w, b, stride, pad) -> str:
                                    "squeezenet"])
 def test_conv_route_table(model):
     """Every zoo conv off the plain 1x1 path (the RGB stems, the fire
-    expands) takes XLA's convolution, and every 1x1 conv the tiled GEMM;
-    no zoo layer reaches the implicit-GEMM kernel."""
+    expands) takes XLA's convolution, and every 1x1 conv the tiled
+    GEMM."""
     routes = {}
     for l in get_graph(model).layers:
         if l.op != "conv":
@@ -159,13 +158,14 @@ def test_xla_route_rule(kh, ci, stride, pad, routed):
     (28, 3, 32, 2),                # the stem's shape, at 28 px
     (14, 32, 64, 1),
     (8, 128, 64, 1)])              # lane-full
-def test_implicit_gemm_kernel_matches_ref(h, ci, co, s, dtype):
-    """The implicit-GEMM kernel called directly (``conv2d_gemm`` sends
-    these convs to XLA): the stem's shape, a fire-like one, lane-full."""
+def test_xla_routed_conv_matches_ref(h, ci, co, s, dtype):
+    """``conv2d_gemm`` on its XLA route at batch 2, f32 and bf16: the
+    stem's shape, a fire-like one, lane-full."""
+    assert xla_routed(3, 3, s, 1)
     x = rand(KEYS[0], (2, h, h, ci), dtype, 0.5)
     w = rand(KEYS[1], (3, 3, ci, co), dtype, 0.2)
     b = rand(KEYS[2], (co,), dtype)
-    out = implicit_gemm_conv(x, w, b, stride=s, pad=1, act="relu6")
+    out = conv2d_gemm(x, w, b, stride=s, pad=1, act="relu6")
     ref = conv2d_ref(x, w, b, stride=s, pad=1, act="relu6")
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(ref, np.float32), **tol(dtype))

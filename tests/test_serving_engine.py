@@ -51,7 +51,7 @@ def test_poisson_arrivals_fixed_and_monotone():
 def test_zero_capacity_queue_rejected():
     """max_queue=0 could never admit work — replay() would spin forever
     retrying QueueFull; both engines must reject it at construction."""
-    runner, _, _ = _runner("mobilenet_v1", use_pallas=False, fuse=False)
+    runner, _, _ = _runner("mobilenet_v1", use_pallas=False)
     with pytest.raises(ValueError, match="max_queue"):
         DualCoreEngine(runner, max_queue=0)
 
@@ -73,7 +73,7 @@ def test_admission_policies_clamp():
 
 
 def test_engines_satisfy_protocol():
-    runner, _, _ = _runner("mobilenet_v1", use_pallas=False, fuse=False)
+    runner, _, _ = _runner("mobilenet_v1", use_pallas=False)
     assert isinstance(DualCoreEngine(runner), Engine)
 
 
@@ -86,16 +86,19 @@ def test_engines_satisfy_protocol():
     pytest.param("squeezenet", marks=pytest.mark.slow),
 ])
 def test_cnn_engine_bitwise_equals_run_sequential(model):
-    """The streaming engine partitions the same step program the strictly
-    serialized baseline runs, so outputs must be bitwise-identical (eager
-    group execution, CPU interpret Pallas kernels)."""
-    runner, _, _ = _runner(model, use_pallas=True, fuse=True,
-                           jit_groups=False)
+    """The streaming engine dispatches the same jitted exec groups the
+    strictly serialized baseline runs, so outputs must be bitwise-identical
+    (CPU interpret Pallas kernels); and they are the sequential Pallas
+    forward's to rounding."""
+    runner, params, fwd = _runner(model, use_pallas=True)
     imgs = _images(2)
     res = stream_images(runner, imgs)
     refs = runner.run_sequential(imgs)
-    for out, ref in zip(res.outputs, refs):
+    for x, out, ref in zip(imgs, res.outputs, refs):
         np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
+        np.testing.assert_allclose(
+            np.asarray(out), np.asarray(fwd(params, x, use_pallas=True)),
+            rtol=2e-3, atol=2e-3)
     assert res.metrics.completed == 2
     assert all(m.finished_at is not None for m in res.metrics.requests)
 
@@ -104,7 +107,7 @@ def test_cnn_engine_slot_refill_trace_bursty_arrivals():
     """Admission refills the group-0 slot online: request r admitted at
     slot s runs group k at slot s+k exactly — including through the bubble
     an empty queue leaves behind."""
-    runner, _, _ = _runner("mobilenet_v1", use_pallas=False, fuse=False)
+    runner, _, _ = _runner("mobilenet_v1", use_pallas=False)
     n_g = len(runner.groups)
     imgs = _images(3, size=32)
     rec = []
@@ -129,7 +132,7 @@ def test_cnn_engine_saturated_trace_matches_run_pipelined():
     """With every request available at slot 0 the engine reproduces the
     static ``run_pipelined`` dispatch schedule exactly (the shim test in
     test_dualcore covers the shim; this drives the engine directly)."""
-    runner, _, _ = _runner("mobilenet_v1", use_pallas=False, fuse=False)
+    runner, _, _ = _runner("mobilenet_v1", use_pallas=False)
     n_g = len(runner.groups)
     rec = []
     stream_images(runner, _images(3, size=32), record=rec)
@@ -139,7 +142,7 @@ def test_cnn_engine_saturated_trace_matches_run_pipelined():
 
 
 def test_cnn_engine_backpressure_and_flight_bound():
-    runner, _, _ = _runner("mobilenet_v1", use_pallas=False, fuse=False)
+    runner, _, _ = _runner("mobilenet_v1", use_pallas=False)
     imgs = _images(4, size=32)
     eng = DualCoreEngine(runner, max_queue=2)
     eng.submit(imgs[0])
@@ -160,8 +163,7 @@ def test_cnn_engine_replay_retries_on_backpressure():
     """replay() pushes submissions past QueueFull to later steps; every
     request still completes, in submission order, bitwise-equal to the
     plain forward."""
-    runner, params, fwd = _runner("mobilenet_v1", use_pallas=False,
-                                  fuse=False)
+    runner, params, fwd = _runner("mobilenet_v1", use_pallas=False)
     imgs = _images(5, size=32)
     eng = DualCoreEngine(runner, max_queue=1)
     res = replay(eng, [Request(x) for x in imgs], [0, 0, 0, 1, 2])
@@ -178,8 +180,7 @@ def test_cnn_engine_single_group_chain():
     admit-and-retire within a slot."""
     params, fwd, g = build_model("squeezenet")
     sched = build_schedule(g, DUAL_BASELINE, B, "layer_type")
-    runner = DualCoreRunner("squeezenet", params, sched, use_pallas=False,
-                            fuse=False)
+    runner = DualCoreRunner("squeezenet", params, sched, use_pallas=False)
     eng = DualCoreEngine(runner)
     assert eng.capacity == 1
     (x,) = _images(1, size=32)

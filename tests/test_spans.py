@@ -213,7 +213,7 @@ FLEET = ("mobilenet_v1", "squeezenet")
 
 def _fleet(**kw):
     """mobilenet_v1 and squeezenet on the XLA path behind one fleet."""
-    return build_cnn_fleet(list(FLEET), use_pallas=False, fuse=False,
+    return build_cnn_fleet(list(FLEET), use_pallas=False,
                            **kw)[0]
 
 

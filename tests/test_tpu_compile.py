@@ -20,8 +20,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.kernels.conv_gemm.ops import (conv2d_gemm, implicit_gemm_conv,
-                                         xla_routed)
+from repro.kernels.conv_gemm.ops import conv2d_gemm, xla_routed
 from repro.kernels.depthwise.ops import depthwise
 from repro.kernels.fused_block.ops import fused_dw_pw, fused_inverted_residual
 
@@ -81,18 +80,6 @@ def test_stem_conv_compiles(one_chip):
 def test_xla_routed_conv_compiles(one_chip, xs, ws):
     """Every other conv off the plain 1x1 path does too."""
     _compiles_to_xla_conv(one_chip, xs, ws, 1)
-
-
-# squeezenet fire2_e3x3 (16 -> 64 channels, 56 px) and a lane-full 3x3 conv
-@pytest.mark.parametrize("xs,ws", [
-    ((1, 56, 56, 16), (3, 3, 16, 64)),
-    ((1, 14, 14, 128), (3, 3, 128, 128))])
-def test_implicit_gemm_conv_compiles(one_chip, xs, ws):
-    """The implicit-GEMM kernel, called directly (``conv2d_gemm`` no
-    longer runs it), still compiles at 3x3 s1."""
-    _compile(lambda x, w, b: implicit_gemm_conv(
-        x, w, b, stride=1, pad=1, act="relu", interpret=False),
-        one_chip, xs, ws, ws[-1:])
 
 
 @pytest.mark.parametrize("shape,stride", [
@@ -176,8 +163,8 @@ def test_fused_dw_pw_compiles(one_chip):
 
 
 # the device-op names the benchmark's roofline readers match
-KERNEL_OPS = {"matmul_bias_act", "conv2d_implicit_gemm", "depthwise_conv2d",
-              "fused_dw_pw_conv", "fused_pw_dw_pw_conv"}
+KERNEL_OPS = {"matmul_bias_act", "depthwise_conv2d", "fused_dw_pw_conv",
+              "fused_pw_dw_pw_conv"}
 _CUSTOM_CALL = re.compile(
     r"^\s*(?:ROOT )?%([\w-]+?)(?:\.\d+)* = .*custom-call\(", re.M)
 _INSTRUCTION = re.compile(r"^\s*(?:ROOT )?%([\w.-]+) = ", re.M)
